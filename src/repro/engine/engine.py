@@ -46,6 +46,16 @@ from repro.models.model import LayeredModel
 from repro.optim import Optimizer, adam
 
 
+PALLAS_TRANSPORT_ON_TPU = (
+    "transport='pallas' does not run on TPU: the relay's stacked rows are "
+    "packed (N, W) segments and per-leaf biases and norm scales, and the "
+    "chip's compiler refuses a 1-row DMA slice of such a 2-D buffer "
+    "('Slice shape along dimension 0 must be aligned to tiling (8)'). "
+    "The chip takes a pinned_host row DMA only along an untiled leading "
+    "axis, of f32 rows shaped (8k, C >= 256), a layout the relay's "
+    "buffers do not have.  Use transport='xla'.")
+
+
 class Engine:
     """Base facade: lifecycle + lazy jit over a schedule's kernels.
 
@@ -62,6 +72,9 @@ class Engine:
             model = LayeredModel(model)
         self.model = model
         self.exec_cfg = self._normalize_cfg(exec_cfg or ExecutionConfig())
+        if (self.exec_cfg.transport == "pallas"
+                and jax.default_backend() == "tpu"):
+            raise NotImplementedError(PALLAS_TRANSPORT_ON_TPU)
         self.optimizer = optimizer or adam()
         self.mesh = mesh
         self._rules = rules
@@ -148,13 +161,34 @@ class Engine:
 
     # -- state lifecycle ----------------------------------------------------
     def init(self, rng) -> TrainState:
-        """Materialize parameters + optimizer state from a PRNG key.
-        With the storage tier enabled the fresh state is adopted by the
-        TierChain: segments written to the store, cold rows demoted."""
-        params = self._relay_params(self.model.init_params(rng))
-        state = TrainState.from_legacy(params, self._init_opt_legacy(params))
+        """Materialize parameters + optimizer state from a PRNG key, placed
+        where the train step keeps them (``_place``).  With the storage
+        tier enabled the fresh state is adopted by the TierChain:
+        segments written to the store, cold rows demoted."""
+        params = self.model.init_params(rng)
+        if self.exec_cfg.pack_params:
+            # not through _relay_params: its identity cache would pin the
+            # unpacked device copy for the engine's lifetime
+            params = packing.pack_params(params)
+        state = self._place(
+            TrainState.from_legacy(params, self._init_opt_legacy(params)))
         if self.tier is not None:
             state = self.tier.adopt(state, step=0)
+        return state
+
+    def _place(self, state: TrainState) -> TrainState:
+        """Commit a fresh or restored state to where the train step keeps
+        it, so that the first step runs the same program as every later
+        one.  Every leaf is committed to the device, as the step's outputs
+        are (jit keys its cache on that), and ``_to_eps`` puts the stacked
+        group rows where the schedule relays them from.  Mesh engines
+        place their state through their own shardings."""
+        if self.mesh is not None:
+            return state
+        return self._to_eps(jax.device_put(state, jax.devices()[0]))
+
+    def _to_eps(self, state: TrainState) -> TrainState:
+        """Schedules without a relay keep the whole state on the device."""
         return state
 
     def abstract_state(self) -> TrainState:
@@ -215,7 +249,7 @@ class Engine:
         if self.exec_cfg.pack_params:
             params = packing.pack_params(params)
             opt = packing.pack_opt_state(opt, params)
-        state = TrainState.from_legacy(params, opt)
+        state = self._place(TrainState.from_legacy(params, opt))
         if self.tier is not None:
             state = self.tier.adopt(state, step=step)
             self.tier.attach_checkpoints(directory, prefix, self)
@@ -435,6 +469,22 @@ class BaselineEngine(Engine):
 
 
 class _L2LBase(Engine):
+    def _to_eps(self, state):
+        """Stacked group rows and their optimizer slots in the EPS: the
+        placements' host memory space (pinned_host on TPU with
+        ``weight_stream``, an identity where the placements are no-ops),
+        the same space the step hands them back in."""
+        pl = self.placements
+
+        def host(places, groups):
+            return tuple(p.host(g) for p, g in zip(places, groups))
+
+        return state.replace(
+            params={**state.params,
+                    "groups": host(pl.weights, state.params["groups"])},
+            opt_state={**state.opt_state,
+                       "groups": host(pl.opts, state.opt_state["groups"])})
+
     def _make_step_kernel(self):
         return _l2l.make_train_step(self.model, self.optimizer,
                                     self.exec_cfg, self.placements)

@@ -16,6 +16,9 @@ early-exit ``wrap`` below being compiled into a cheap branch.
 
 Layouts: q,k,v as (B, H, S, D) with D and the S blocks aligned to the MXU
 (block defaults 128/512 lanes).  fp32 accumulation regardless of input dtype.
+Per-row statistics (the online-softmax m and l, the saved lse, the
+backward's delta) are (rows, 1) columns, so every block's last two dims
+meet the TPU's (8, 128) rule: a row block of 8k and a full unit dim.
 """
 from __future__ import annotations
 
@@ -43,6 +46,18 @@ def _block_mask(iq, ik, block_q, block_k, causal, window):
     return allow
 
 
+def _dot_nt(a, b):
+    """a @ b.T without materializing the transpose (MXU-native form)."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _dot_tn(a, b):
+    """a.T @ b without materializing the transpose."""
+    return jax.lax.dot_general(a, b, (((0,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
 def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
                scale: float, causal: bool, window: int, soft_cap: float,
                block_q: int, block_k: int, n_k: int):
@@ -57,19 +72,19 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
 
     q = q_ref[0, 0].astype(jnp.float32) * scale          # (bq, d)
     k = k_ref[0, 0].astype(jnp.float32)                  # (bk, d)
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)  # (bq, bk)
+    s = _dot_nt(q, k)                                    # (bq, bk)
     if soft_cap > 0:
         s = soft_cap * jnp.tanh(s / soft_cap)
 
     allow = _block_mask(iq, ik, block_q, block_k, causal, window)
     s = jnp.where(allow, s, NEG_INF)
 
-    m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=1))
-    p = jnp.exp(s - m_new[:, None])
+    m_prev = m_scr[...]                                  # (bq, 1)
+    m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)
     alpha = jnp.exp(m_prev - m_new)
-    l_scr[...] = l_scr[...] * alpha + p.sum(axis=1)
-    acc_scr[...] = acc_scr[...] * alpha[:, None] + jnp.dot(
+    l_scr[...] = l_scr[...] * alpha + p.sum(axis=1, keepdims=True)
+    acc_scr[...] = acc_scr[...] * alpha + jnp.dot(
         p, v_ref[0, 0].astype(jnp.float32),
         preferred_element_type=jnp.float32)
     m_scr[...] = m_new
@@ -77,7 +92,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
     @pl.when(ik == n_k - 1)
     def _flush():
         l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, 0] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
         lse_ref[0, 0] = m_scr[...] + jnp.log(l)
 
 
@@ -85,8 +100,8 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
     "causal", "window", "soft_cap", "block_q", "block_k", "interpret"))
 def flash_attention_fwd_bhsd(q, k, v, *, causal=True, window=0,
                              soft_cap=0.0, block_q=128, block_k=128,
-                             interpret=True):
-    """q,k,v: (B,H,S,D) -> (o (B,H,S,D), lse (B,H,S))."""
+                             interpret):
+    """q,k,v: (B,H,S,D) -> (o (B,H,S,D), lse (B,H,S,1))."""
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
     block_q = min(block_q, Sq)
@@ -111,26 +126,18 @@ def flash_attention_fwd_bhsd(q, k, v, *, causal=True, window=0,
         out_specs=(
             pl.BlockSpec((1, 1, block_q, D),
                          lambda b, h, iq, ik: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, iq, ik: (b, h, iq)),
+            pl.BlockSpec((1, 1, block_q, 1),
+                         lambda b, h, iq, ik: (b, h, iq, 0)),
         ),
         out_shape=(jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype),
-                   jax.ShapeDtypeStruct((B, H, Sq), jnp.float32)),
+                   jax.ShapeDtypeStruct((B, H, Sq, 1), jnp.float32)),
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
         interpret=interpret,
     )(q, k, v)
-
-
-def flash_attention_bhsd(q, k, v, *, causal=True, window=0, soft_cap=0.0,
-                         block_q=128, block_k=128, interpret=True):
-    """Forward-only convenience wrapper -> o (B,H,S,D)."""
-    o, _ = flash_attention_fwd_bhsd(
-        q, k, v, causal=causal, window=window, soft_cap=soft_cap,
-        block_q=block_q, block_k=block_k, interpret=interpret)
-    return o
 
 
 # ===========================================================================
@@ -155,15 +162,15 @@ def _fa_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)
     do = do_ref[0, 0].astype(jnp.float32)
-    lse = lse_ref[0, 0]
-    delta = delta_ref[0, 0]
+    lse = lse_ref[0, 0]                                  # (bq, 1)
+    delta = delta_ref[0, 0]                              # (bq, 1)
 
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
+    s = _dot_nt(q, k)
     allow = _block_mask(iq, ik, block_q, block_k, causal, window)
     s = jnp.where(allow, s, NEG_INF)
-    p = jnp.exp(s - lse[:, None])
-    dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-    ds = p * (dp - delta[:, None])
+    p = jnp.exp(s - lse)
+    dp = _dot_nt(do, v)
+    ds = p * (dp - delta)
     dq_scr[...] += jnp.dot(ds, k, preferred_element_type=jnp.float32)
 
     @pl.when(ik == n_k - 1)
@@ -186,17 +193,17 @@ def _fa_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)
     do = do_ref[0, 0].astype(jnp.float32)
-    lse = lse_ref[0, 0]
-    delta = delta_ref[0, 0]
+    lse = lse_ref[0, 0]                                  # (bq, 1)
+    delta = delta_ref[0, 0]                              # (bq, 1)
 
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
+    s = _dot_nt(q, k)
     allow = _block_mask(iq, ik, block_q, block_k, causal, window)
     s = jnp.where(allow, s, NEG_INF)
-    p = jnp.exp(s - lse[:, None])                          # (bq, bk)
-    dv_scr[...] += jnp.dot(p.T, do, preferred_element_type=jnp.float32)
-    dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-    ds = p * (dp - delta[:, None])
-    dk_scr[...] += jnp.dot(ds.T, q, preferred_element_type=jnp.float32)
+    p = jnp.exp(s - lse)                                 # (bq, bk)
+    dv_scr[...] += _dot_tn(p, do)
+    dp = _dot_nt(do, v)
+    ds = p * (dp - delta)
+    dk_scr[...] += _dot_tn(ds, q)
 
     @pl.when(iq == n_q - 1)
     def _flush():
@@ -208,7 +215,7 @@ def _fa_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 @functools.partial(jax.jit, static_argnames=(
     "causal", "window", "block_q", "block_k", "interpret"))
 def flash_attention_bwd_bhsd(q, k, v, o, lse, do, *, causal=True, window=0,
-                             block_q=128, block_k=128, interpret=True):
+                             block_q=128, block_k=128, interpret):
     """-> (dq, dk, dv), all (B,H,S,D).  soft_cap unsupported in bwd (the
     models that train with the kernel don't cap; grok's capped logits are
     in the head, not attention)."""
@@ -218,13 +225,15 @@ def flash_attention_bwd_bhsd(q, k, v, o, lse, do, *, causal=True, window=0,
     block_k = min(block_k, Sk)
     n_q, n_k = Sq // block_q, Sk // block_k
     scale = 1.0 / math.sqrt(D)
-    delta = (do.astype(jnp.float32) * o.astype(jnp.float32)).sum(-1)
+    delta = (do.astype(jnp.float32) * o.astype(jnp.float32)).sum(
+        -1, keepdims=True)
 
     q_spec_q = pl.BlockSpec((1, 1, block_q, D),
                             lambda b, h, iq, ik: (b, h, iq, 0))
     k_spec_q = pl.BlockSpec((1, 1, block_k, D),
                             lambda b, h, iq, ik: (b, h, ik, 0))
-    r_spec_q = pl.BlockSpec((1, 1, block_q), lambda b, h, iq, ik: (b, h, iq))
+    r_spec_q = pl.BlockSpec((1, 1, block_q, 1),
+                            lambda b, h, iq, ik: (b, h, iq, 0))
 
     dq = pl.pallas_call(
         functools.partial(_fa_dq_kernel, scale=scale, causal=causal,
@@ -243,7 +252,8 @@ def flash_attention_bwd_bhsd(q, k, v, o, lse, do, *, causal=True, window=0,
                             lambda b, h, ik, iq: (b, h, iq, 0))
     k_spec_k = pl.BlockSpec((1, 1, block_k, D),
                             lambda b, h, ik, iq: (b, h, ik, 0))
-    r_spec_k = pl.BlockSpec((1, 1, block_q), lambda b, h, ik, iq: (b, h, iq))
+    r_spec_k = pl.BlockSpec((1, 1, block_q, 1),
+                            lambda b, h, ik, iq: (b, h, iq, 0))
 
     dk, dv = pl.pallas_call(
         functools.partial(_fa_dkv_kernel, scale=scale, causal=causal,

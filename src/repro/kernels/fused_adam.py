@@ -46,7 +46,7 @@ def _adam_kernel(scal_ref, p_ref, g_ref, m_ref, v_ref,
     "b1", "b2", "eps", "wd", "wd_form", "block", "interpret"))
 def fused_adam_flat(p, g, m, v, a, clip_scale, *, b1=0.9, b2=0.999,
                     eps=1e-8, wd=0.0, wd_form=None, block=16384,
-                    interpret=True):
+                    interpret):
     """All arrays 1-D of equal length (pad to block multiple).  ``a`` and
     ``clip_scale`` are f32 scalars (traced).  ``wd_form`` forces the
     adamw update association even when wd == 0 (None = infer from wd)."""

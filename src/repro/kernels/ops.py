@@ -1,8 +1,10 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On this container (CPU backend) the kernels execute in interpret mode —
-the TPU lowering is the target, interpret is the validation harness.
-``interpret`` defaults to True unless a TPU backend is present.
+The TPU lowering is the target; on any other backend the kernels run in
+Pallas interpret mode, the validation harness.  ``interpret_default`` is
+the one place that choice is made: the raw kernels take ``interpret``
+as a required argument, and these wrappers (and ``relay_copy``) fill it
+from here when the caller passes None.
 """
 from __future__ import annotations
 
@@ -17,7 +19,8 @@ from repro.kernels.fused_adam import fused_adam_flat
 from repro.kernels.rmsnorm import rmsnorm_2d
 
 
-def _interpret_default() -> bool:
+def interpret_default() -> bool:
+    """Interpret mode everywhere but on a TPU backend."""
     return jax.default_backend() != "tpu"
 
 
@@ -58,7 +61,7 @@ def flash_attention(q, k, v, *, causal=True, window=0, soft_cap=0.0,
                     block_q=128, block_k=128, interpret=None):
     """q,k,v: (B,S,H,D) (model layout) -> (B,S,H,D).  Differentiable
     (custom VJP with recompute backward)."""
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = interpret_default() if interpret is None else interpret
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
@@ -71,7 +74,7 @@ def fused_adam(p, g, m, v, a, clip_scale, *, b1=0.9, b2=0.999, eps=1e-8,
                wd=0.0, wd_form=None, interpret=None):
     """Arbitrary-shaped params: flattens, pads to the block size, runs the
     fused kernel, restores shape.  Returns (p', m', v')."""
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = interpret_default() if interpret is None else interpret
     shape = p.shape
     n = p.size
     block = min(16384, max(128, 1 << (n - 1).bit_length()))
@@ -91,7 +94,7 @@ def fused_adam(p, g, m, v, a, clip_scale, *, b1=0.9, b2=0.999, eps=1e-8,
 
 def rmsnorm(x, scale, *, eps=1e-6, interpret=None):
     """x: (..., d) -> same shape.  Forward only — see ``rmsnorm_diff``."""
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = interpret_default() if interpret is None else interpret
     shape = x.shape
     x2 = x.reshape(-1, shape[-1])
     r = x2.shape[0]
@@ -141,5 +144,5 @@ _rmsnorm_ad.defvjp(_rmsnorm_ad_fwd, _rmsnorm_ad_bwd)
 
 def rmsnorm_diff(x, scale, *, eps=1e-6, interpret=None):
     """Differentiable fused RMSNorm: x (..., d), scale (d,) -> (..., d)."""
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = interpret_default() if interpret is None else interpret
     return _rmsnorm_ad(x, scale, eps, interpret)

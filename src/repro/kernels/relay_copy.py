@@ -19,10 +19,14 @@ Pallas DMA pipeline, the ``emit_pipeline`` idiom by hand:
   start, so two transfers are always in flight — overlap guaranteed by
   the semaphores, not by scheduler luck.
 
-On TPU the source lives in host/ANY memory and the copy is a real
-host->HBM DMA; on CPU (this container / CI) the kernel runs in interpret
-mode and the semantics — bit-exact movement of rows ``[start, start+size)``
-— are what the transport tests pin down.  The kernel never needs a
+The chip's compiler refuses this kernel: a 1-row slice of a 2-D
+``(N, W)`` buffer is not aligned to the (8, 128) tiling, and a
+``pinned_host`` source is a memory space the kernel cannot address
+(``tests/test_tpu_compile.py``).  The engines therefore refuse
+``transport="pallas"`` on TPU (``engine.PALLAS_TRANSPORT_ON_TPU``).  On
+any other backend the kernel runs in interpret mode, and its semantics —
+bit-exact movement of rows ``[start, start+size)`` — are what the
+transport tests pin down.  The kernel never needs a
 custom VJP: ``relay_scan``'s fetch is not differentiated (the backward
 vjp closes over the already-fetched slot), and the write-back direction
 is an identity copy on the produced values.
@@ -36,9 +40,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+from repro.kernels.ops import interpret_default
 
 
 def _chunk_plan(size: int, width: int) -> tuple:
@@ -80,7 +82,7 @@ def copy_rows(src, start, *, size: int, interpret=None):
     by the double-buffered DMA pipeline.  ``start`` may be traced (it is
     the relay scan's stop index); ``size`` is static.  Bit-exact to
     ``jax.lax.dynamic_slice_in_dim(src, start, size)``."""
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = interpret_default() if interpret is None else interpret
     n, w = src.shape
     chunks = _chunk_plan(size, w)
     start = jnp.asarray(start, jnp.int32).reshape((1,))

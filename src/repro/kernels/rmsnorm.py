@@ -23,7 +23,7 @@ def _rmsnorm_kernel(x_ref, s_ref, o_ref, *, eps):
 
 @functools.partial(jax.jit, static_argnames=("eps", "block_rows",
                                              "interpret"))
-def rmsnorm_2d(x, scale, *, eps=1e-6, block_rows=256, interpret=True):
+def rmsnorm_2d(x, scale, *, eps=1e-6, block_rows=256, interpret):
     """x: (R, d), scale: (d,) -> (R, d)."""
     R, d = x.shape
     block_rows = min(block_rows, R)

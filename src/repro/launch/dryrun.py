@@ -1,11 +1,5 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (architecture x input shape)
 on the production meshes and extract the roofline terms.
-
-The two lines above MUST stay the first statements in this module (before
-any jax import) — jax locks the device count on first init.
 
 Usage::
 
@@ -16,6 +10,16 @@ Usage::
 Results land in experiments/dryrun/<mesh>/<arch>__<shape>.json and feed
 EXPERIMENTS.md §Dry-run and §Roofline.
 """
+import os
+
+# a CPU lowering tool: it never takes an accelerator, and it adds its
+# host device count to whatever XLA_FLAGS the caller set (before any
+# backend starts: the count is fixed then)
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ["XLA_FLAGS"] = " ".join(dict.fromkeys(
+    os.environ.get("XLA_FLAGS", "").split()
+    + ["--xla_force_host_platform_device_count=512"]))
+
 import argparse
 import json
 import re

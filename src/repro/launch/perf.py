@@ -1,12 +1,19 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Perf hillclimb runner (§Perf): re-lowers the chosen (arch x shape)
 pairs with candidate optimizations and reports before/after roofline
 terms.  Results land in experiments/dryrun/perf/ and EXPERIMENTS.md.
 
     PYTHONPATH=src python -m repro.launch.perf [--pair NAME] [--multi]
 """
+import os
+
+# a CPU lowering tool: it never takes an accelerator, and it adds its
+# host device count to whatever XLA_FLAGS the caller set (before any
+# backend starts: the count is fixed then)
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ["XLA_FLAGS"] = " ".join(dict.fromkeys(
+    os.environ.get("XLA_FLAGS", "").split()
+    + ["--xla_force_host_platform_device_count=512"]))
+
 import argparse
 import json
 

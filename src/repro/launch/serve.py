@@ -25,6 +25,7 @@ import numpy as np
 
 from repro import engine as engines
 from repro.configs.base import get_config
+from repro.launch import compile_cache
 from repro.core.schedule import ExecutionConfig
 from repro.serve.engine import ServeConfig
 from repro.serve.sampling import sample_batch
@@ -214,4 +215,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -7,9 +7,12 @@ pipeline::
     PYTHONPATH=src python -m repro.launch.train --arch bert-large \
         --engine l2l-p --steps 300 --batch 32 --seq 128 --ub 4
 
-On a real TPU pod this same driver runs under the production mesh with
-``--mesh single|multi`` (sharded params, per-layer eager reduction); on CPU
-it runs unsharded.  Checkpoints via the engine's save/restore.
+It runs on one device, unsharded: with ``--weight-stream`` on a TPU the
+stacked layers and their optimizer slots live in pinned host memory and
+are relayed to HBM one layer at a time (on CPU the placements are
+logical only).  Run as a script, it keeps JAX's compile cache in
+``JAX_COMPILATION_CACHE_DIR`` or ``<checkout>/.jax_cache``
+(``launch.compile_cache``).  Checkpoints via the engine's save/restore.
 
 Preemption safety: checkpoints are crash-consistent (staged + fsynced +
 atomically renamed, crc32-verified on restore — ``repro.checkpoint.io``),
@@ -35,6 +38,7 @@ import numpy as np
 from repro import engine as engines
 from repro.checkpoint import io as ckpt_io
 from repro.configs.base import get_config
+from repro.launch import compile_cache
 from repro.core.schedule import ExecutionConfig
 from repro.data.synthetic import DataConfig, SyntheticLM, add_modality_stubs
 from repro.optim.optimizers import get_optimizer, make_schedule
@@ -321,4 +325,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -185,6 +185,9 @@ def launch_train(argv: List[str]) -> subprocess.Popen:
     env["PYTHONPATH"] = _SRC + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
     env.setdefault("JAX_PLATFORMS", "cpu")
+    # the script enables the checkout's compile cache; a test run
+    # leaves no cache behind
+    env.setdefault("JAX_ENABLE_COMPILATION_CACHE", "false")
     return subprocess.Popen(
         [sys.executable, "-m", "repro.launch.train", *argv],
         stdout=subprocess.PIPE, stderr=subprocess.STDOUT,

@@ -115,6 +115,42 @@ def test_engine_save_restore_roundtrip(tmp_path, make_engine):
         state.params, restored.params))
 
 
+@pytest.mark.parametrize("name", ["baseline", "l2l-p"])
+def test_init_state_runs_the_steps_program(name, make_engine):
+    """``init`` hands over a state the step's own outputs look like, so
+    the first step compiles the program every later step runs."""
+    cfg = get_config("bert-large", "smoke").replace(dtype="float32")
+    eng = make_engine(name)
+    state = eng.init(jax.random.PRNGKey(0))
+    assert all(a.committed for a in jax.tree.leaves(state))
+    for seed in (0, 1):
+        state, _ = eng.train_step(state, make_batch(cfg, 4, 16, seed=seed))
+    assert eng._fns["train_step"]._cache_size() == 1
+
+
+def test_init_and_restore_put_stacked_rows_in_the_eps(tmp_path, make_engine):
+    """The stacked rows and their optimizer slots go through the EPS
+    placements' host move at init and at restore (pinned_host on TPU)."""
+    from repro.core.eps import EPSPlacements, Placement, noop_placement
+    moved = []
+
+    def host(tree):
+        moved.append(tree)
+        return tree
+
+    ident = lambda t: t
+    eps_host = Placement(host, ident, ident)
+    eng = make_engine("l2l-p", placements=EPSPlacements(
+        (eps_host,), (eps_host,), noop_placement()))
+    state = eng.init(jax.random.PRNGKey(0))
+    assert moved == [state.params["groups"][0],
+                     state.opt_state["groups"][0]]
+    eng.save(str(tmp_path), state, step=1)
+    restored, _ = eng.restore(str(tmp_path))
+    assert moved[2:] == [restored.params["groups"][0],
+                         restored.opt_state["groups"][0]]
+
+
 # ---------------------------------------------------------------------------
 # inference + analysis surface
 # ---------------------------------------------------------------------------
