@@ -75,10 +75,11 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.core import packing
+from repro.core import packing, spans
 from repro.core.eps import EPSPlacements, make_placements
 from repro.core.relay import (Stream, flatten_segments, group_slice,
-                              relay_scan, segment_bounds, segment_scan)
+                              relay_scan, segment_bounds, segment_scan,
+                              to_device, to_host)
 from repro.core.schedule import ExecutionConfig
 from repro.optim import Optimizer, clip_by_norm, tree_global_norm
 
@@ -110,20 +111,22 @@ def _concat_segs(trees):
 
 
 def _make_ship(transport: str) -> Callable:
-    """The write-back half of the relay transport: ``ship(place, tree)``
-    re-hosts a relay stop's products (boundary stash, shipped grads,
-    updated weights / optimizer slots).  Under ``transport="pallas"`` the
-    produced buffer first moves through the same double-buffered DMA
-    pipeline the stream-in uses (``kernels.relay_copy.writeback_slot`` —
-    an identity copy, so the math is untouched), pacing the outbound
-    transfer with semaphores exactly like the inbound one."""
+    """The write-back half of the relay transport: ``ship(placement,
+    tree)`` re-hosts a relay stop's products (boundary stash, shipped
+    grads, updated weights / optimizer slots) through ``relay.to_host``.
+    Under ``transport="pallas"`` the produced buffer first moves through
+    the same double-buffered DMA pipeline the stream-in uses
+    (``kernels.relay_copy.writeback_slot`` — an identity copy, so the math
+    is untouched), pacing the outbound transfer with semaphores exactly
+    like the inbound one."""
     if transport == "pallas":
         from repro.kernels import relay_copy
 
-        def ship(place, tree):
-            return place(relay_copy.writeback_slot(tree))
+        def ship(placement, tree):
+            with jax.named_scope(spans.WRITEBACK):
+                return to_host(placement, relay_copy.writeback_slot(tree))
         return ship
-    return lambda place, tree: place(tree)
+    return to_host
 
 
 def _make_packed_update(optimizer: Optimizer, exec_cfg: ExecutionConfig,
@@ -138,6 +141,7 @@ def _make_packed_update(optimizer: Optimizer, exec_cfg: ExecutionConfig,
     (lamb/sgd/collector, or host_optimizer which must run on the EPS
     host): unpack -> per-leaf ``run_opt`` -> repack.  Both paths are
     bit-identical to the unpacked schedule."""
+    @jax.named_scope(spans.OPTIMIZER)
     def packed_update(dw, opt_l, w_pk, step_i):
         spec = w_pk.spec
         slots = tuple(sorted(opt_l))
@@ -189,6 +193,7 @@ def make_train_step(model, optimizer: Optimizer, exec_cfg: ExecutionConfig,
             "dynamic_depth needs stash_every to divide the capacity depth"
     ship = _make_ship(TR)
 
+    @jax.named_scope(spans.OPTIMIZER)
     def run_opt(grads, opt_l, w, step_i):
         """Apply the optimizer — on the EPS host when host_optimizer (the
         paper's CPU optimizer, eq. (6) O_tc; L2L-p overlaps it)."""
@@ -220,6 +225,7 @@ def make_train_step(model, optimizer: Optimizer, exec_cfg: ExecutionConfig,
         # ------------------------------------------------------------
         # FORWARD: layer-major relay through the groups
         # ------------------------------------------------------------
+        @jax.named_scope(spans.HEAD)
         def prep_one(b):
             x, _ = model.prepare(static, b)
             return x
@@ -240,13 +246,15 @@ def make_train_step(model, optimizer: Optimizer, exec_cfg: ExecutionConfig,
         for gi, group in enumerate(model.groups):
             if gi > 0:
                 x_prev = x_ub
-                x_ub = jax.lax.map(
-                    lambda b_x: model.transition_x(gi, static, b_x[1], b_x[0]),
-                    (batch_ub, x_prev))
-                mem_ub = (jax.lax.map(
-                    lambda b_x: model.transition_mem(gi, static, b_x[1],
-                                                     b_x[0]),
-                    (batch_ub, x_prev)) if group.has_mem else None)
+                with jax.named_scope(spans.HEAD):
+                    x_ub = jax.lax.map(
+                        lambda b_x: model.transition_x(gi, static, b_x[1],
+                                                       b_x[0]),
+                        (batch_ub, x_prev))
+                    mem_ub = (jax.lax.map(
+                        lambda b_x: model.transition_mem(gi, static, b_x[1],
+                                                         b_x[0]),
+                        (batch_ub, x_prev)) if group.has_mem else None)
                 group_inputs.append(x_prev)   # saved for transition vjp
             else:
                 mem_ub = None
@@ -255,6 +263,7 @@ def make_train_step(model, optimizer: Optimizer, exec_cfg: ExecutionConfig,
             ctx = model.train_ctx(ub_slice, group)
             wp = placements.weights[gi]
 
+            @jax.named_scope(spans.FORWARD)
             def fwd_body(x_c, slots, _x, _g=group, _ctx=ctx, _mem=mem_ub,
                          _stash=True):
                 """Microbatch loop of one layer (slot already in HBM)."""
@@ -271,7 +280,7 @@ def make_train_step(model, optimizer: Optimizer, exec_cfg: ExecutionConfig,
                     return aux_c + aux.astype(jnp.float32), y
                 xs = x_c if _mem is None else (x_c, _mem)
                 aux_g, y_ub = jax.lax.scan(ub_body, jnp.float32(0.0), xs)
-                return y_ub, ((ship(placements.stash.host, x_c), aux_g)
+                return y_ub, ((ship(placements.stash, x_c), aux_g)
                               if _stash else aux_g)
 
             if SE == 1:
@@ -281,7 +290,7 @@ def make_train_step(model, optimizer: Optimizer, exec_cfg: ExecutionConfig,
                         # inactive layer: activations pass through
                         # untouched; the boundary ships anyway (the ys
                         # avals must match the live branch)
-                        return x_c, (ship(placements.stash.host, x_c),
+                        return x_c, (ship(placements.stash, x_c),
                                      jnp.float32(0.0))
                 x_ub, (stash_g, aux_per_layer) = relay_scan(
                     fwd_body, x_ub, (Stream(wp, params["groups"][gi]),),
@@ -303,7 +312,7 @@ def make_train_step(model, optimizer: Optimizer, exec_cfg: ExecutionConfig,
                     # compile-time A/B baseline (segment_scan=False)
                     stash_segs = []
                     for s0, s1 in segment_bounds(group.n_layers, SE):
-                        stash_segs.append(ship(placements.stash.host, x_ub))
+                        stash_segs.append(ship(placements.stash, x_ub))
                         x_ub, aux_per_layer = relay_scan(
                             fwd_nostash, x_ub,
                             (Stream(wp, _seg_slice(params["groups"][gi],
@@ -329,7 +338,7 @@ def make_train_step(model, optimizer: Optimizer, exec_cfg: ExecutionConfig,
                     def seg_fwd(carry, s0, size, _x, win, _wp=wp,
                                 _w=w_g, _idle=fwd_idle):
                         x_c, aux_c = carry
-                        entry = ship(placements.stash.host, x_c)
+                        entry = ship(placements.stash, x_c)
                         x_c, aux_per_layer = relay_scan(
                             fwd_nostash, x_c,
                             (Stream(_wp, group_slice(_w, s0, size)),),
@@ -359,9 +368,10 @@ def make_train_step(model, optimizer: Optimizer, exec_cfg: ExecutionConfig,
                 lambda a: a.astype(jnp.float32), ds_i)),
                 loss_acc + loss_i), dx_i
 
-        (d_static, loss_sum), dx_ub = jax.lax.scan(
-            head_ub, (_tree_zeros_f32(static), jnp.float32(0.0)),
-            (x_ub, batch_ub))
+        with jax.named_scope(spans.HEAD):
+            (d_static, loss_sum), dx_ub = jax.lax.scan(
+                head_ub, (_tree_zeros_f32(static), jnp.float32(0.0)),
+                (x_ub, batch_ub))
         loss = loss_sum / W_total + aux_total
 
         # ------------------------------------------------------------
@@ -385,6 +395,7 @@ def make_train_step(model, optimizer: Optimizer, exec_cfg: ExecutionConfig,
                 lambda a: jnp.zeros(a.shape, a.dtype), mem_ub)
                 if has_mem else None)
 
+            @jax.named_scope(spans.BACKWARD)
             def bwd_body(core, slots, stash_l, _g=group, _ctx=ctx,
                          _mem=mem_ub, _wp=wp, _op=op, _has_mem=has_mem):
                 """Recompute-vjp microbatch loop (+ eager opt) of one
@@ -396,7 +407,7 @@ def make_train_step(model, optimizer: Optimizer, exec_cfg: ExecutionConfig,
                 opt_l = slots[1] if len(slots) > 1 else None
                 dx_c, dmem_c, gn_c, nf_c = core
                 w_tree = packing.unpack(w_dev) if PK else w_dev
-                stash_dev = placements.stash.dev(stash_l)
+                stash_dev = to_device(placements.stash, stash_l)
 
                 def ub_body(dw_acc, args):
                     if _has_mem:
@@ -440,19 +451,20 @@ def make_train_step(model, optimizer: Optimizer, exec_cfg: ExecutionConfig,
                     if amp:
                         # L2L-adapted AMP: a non-finite layer skips ITS
                         # update (eager updates can't await a global check)
-                        new_w = jax.tree.map(
-                            lambda n, o: jnp.where(finite_l, n, o),
-                            new_w, w_dev)
-                        new_opt = jax.tree.map(
-                            lambda n, o: jnp.where(finite_l, n, o),
-                            new_opt, opt_l)
-                    out = (ship(_wp.host, new_w), ship(_op.host, new_opt))
+                        with jax.named_scope(spans.OPTIMIZER):
+                            new_w = jax.tree.map(
+                                lambda n, o: jnp.where(finite_l, n, o),
+                                new_w, w_dev)
+                            new_opt = jax.tree.map(
+                                lambda n, o: jnp.where(finite_l, n, o),
+                                new_opt, opt_l)
+                    out = (ship(_wp, new_w), ship(_op, new_opt))
                 else:
                     # Alg 3: gradients are shipped to the EPS (host) and the
                     # update happens in a trailing layer loop — packed, the
                     # shipment is one flat f32 segment aligned to the
                     # weight layout instead of N leaf copies.
-                    out = ship(_wp.host,
+                    out = ship(_wp,
                                packing.pack(dw, spec=w_dev.spec,
                                             stacked=False)
                                if PK else dw)
@@ -468,12 +480,12 @@ def make_train_step(model, optimizer: Optimizer, exec_cfg: ExecutionConfig,
                     params/opt slots stay bit-identical) or a zero
                     gradient (trailing-update mode)."""
                     if exec_cfg.eager_optimizer:
-                        return core, (ship(_wp.host, slots[0]),
-                                      ship(_op.host, slots[1]))
+                        return core, (ship(_wp, slots[0]),
+                                      ship(_op, slots[1]))
                     w_tree = packing.unpack(slots[0]) if PK else slots[0]
                     dw0 = _tree_zeros_f32(w_tree)
                     return core, ship(
-                        _wp.host,
+                        _wp,
                         packing.pack(dw0, spec=slots[0].spec,
                                      stacked=False) if PK else dw0)
 
@@ -502,6 +514,7 @@ def make_train_step(model, optimizer: Optimizer, exec_cfg: ExecutionConfig,
                 # at a time by the backward (exactly the K=1 protocol),
                 # so the device never holds more than one boundary of
                 # recompute working set regardless of K.
+                @jax.named_scope(spans.RECOMPUTE)
                 def rec_body(x_c, slots, _x, _g=group, _ctx=ctx,
                              _mem=mem_ub):
                     """One layer of the boundary-recompute forward: the
@@ -520,7 +533,7 @@ def make_train_step(model, optimizer: Optimizer, exec_cfg: ExecutionConfig,
                         return None, y
                     xs_l = x_c if _mem is None else (x_c, _mem)
                     _, y_ub = jax.lax.scan(ub_body, None, xs_l)
-                    return y_ub, ship(placements.stash.host, y_ub)
+                    return y_ub, ship(placements.stash, y_ub)
 
                 if not SEG:
                     # historical unrolled per-segment relays
@@ -532,7 +545,7 @@ def make_train_step(model, optimizer: Optimizer, exec_cfg: ExecutionConfig,
                         entry = stashes[gi][si]          # host-placed
                         if s1 - s0 > 1:
                             _, rec_bounds = relay_scan(
-                                rec_body, placements.stash.dev(entry),
+                                rec_body, to_device(placements.stash, entry),
                                 (Stream(wp,
                                         _seg_slice(params["groups"][gi],
                                                    s0, s1 - 1)),),
@@ -572,7 +585,7 @@ def make_train_step(model, optimizer: Optimizer, exec_cfg: ExecutionConfig,
                     rec_idle = None
                     if DYN:
                         def rec_idle(x_c, slots, _x):
-                            return x_c, ship(placements.stash.host, x_c)
+                            return x_c, ship(placements.stash, x_c)
 
                     w_g = params["groups"][gi]
                     o_g = (opt_state["groups"][gi]
@@ -588,7 +601,7 @@ def make_train_step(model, optimizer: Optimizer, exec_cfg: ExecutionConfig,
                                        (win[0],
                                         jnp.maximum(win[1] - 1, 0)))
                             _, rec_bounds = relay_scan(
-                                rec_body, placements.stash.dev(entry),
+                                rec_body, to_device(placements.stash, entry),
                                 (Stream(_wp,
                                         group_slice(_w, s0, size - 1)),),
                                 group=G, prefetch=PF, unroll=UNROLL,
@@ -651,9 +664,10 @@ def make_train_step(model, optimizer: Optimizer, exec_cfg: ExecutionConfig,
                         lambda a: a.astype(jnp.float32), ds_x)), dxp_x
 
                 if has_mem:
-                    d_static, dx_ub = jax.lax.scan(
-                        trans_ub, d_static,
-                        (batch_ub, x_prev_ub, dx_ub, dmem_ub))
+                    with jax.named_scope(spans.HEAD):
+                        d_static, dx_ub = jax.lax.scan(
+                            trans_ub, d_static,
+                            (batch_ub, x_prev_ub, dx_ub, dmem_ub))
                 else:
                     def trans_ub_nomem(d_static_acc, args):
                         b_i, xp_i, dxin_i = args
@@ -663,9 +677,10 @@ def make_train_step(model, optimizer: Optimizer, exec_cfg: ExecutionConfig,
                         ds_x, dxp_x = vjp_x(dxin_i)
                         return _tree_add(d_static_acc, jax.tree.map(
                             lambda a: a.astype(jnp.float32), ds_x)), dxp_x
-                    d_static, dx_ub = jax.lax.scan(
-                        trans_ub_nomem, d_static,
-                        (batch_ub, x_prev_ub, dx_ub))
+                    with jax.named_scope(spans.HEAD):
+                        d_static, dx_ub = jax.lax.scan(
+                            trans_ub_nomem, d_static,
+                            (batch_ub, x_prev_ub, dx_ub))
 
         # ---- prepare (embedding) vjp ---------------------------------
         def prep_ub(d_static_acc, args):
@@ -678,7 +693,8 @@ def make_train_step(model, optimizer: Optimizer, exec_cfg: ExecutionConfig,
             return _tree_add(d_static_acc, jax.tree.map(
                 lambda a: a.astype(jnp.float32), ds_i)), None
 
-        d_static, _ = jax.lax.scan(prep_ub, d_static, (batch_ub, dx_ub))
+        with jax.named_scope(spans.HEAD):
+            d_static, _ = jax.lax.scan(prep_ub, d_static, (batch_ub, dx_ub))
         gnorm_sq = gnorm_sq + tree_global_norm(d_static) ** 2
 
         # ------------------------------------------------------------
@@ -690,15 +706,19 @@ def make_train_step(model, optimizer: Optimizer, exec_cfg: ExecutionConfig,
         nonfinite = nonfinite + jnp.where(finite_s, 0, 1)
         if exec_cfg.clip_mode == "per_layer":
             d_static, _ = clip_by_norm(d_static, exec_cfg.clip_norm)
-        new_static, new_static_opt = optimizer.update(
-            d_static, {"embed": opt_state["embed"], "head": opt_state["head"]},
-            static, opt_step)
-        if amp:
-            new_static = jax.tree.map(
-                lambda n, o: jnp.where(finite_s, n, o), new_static, static)
-            new_static_opt = jax.tree.map(
-                lambda n, o: jnp.where(finite_s, n, o), new_static_opt,
-                {"embed": opt_state["embed"], "head": opt_state["head"]})
+        with jax.named_scope(spans.OPTIMIZER):
+            new_static, new_static_opt = optimizer.update(
+                d_static,
+                {"embed": opt_state["embed"], "head": opt_state["head"]},
+                static, opt_step)
+            if amp:
+                new_static = jax.tree.map(
+                    lambda n, o: jnp.where(finite_s, n, o), new_static,
+                    static)
+                new_static_opt = jax.tree.map(
+                    lambda n, o: jnp.where(finite_s, n, o), new_static_opt,
+                    {"embed": opt_state["embed"],
+                     "head": opt_state["head"]})
 
         if not exec_cfg.eager_optimizer:
             # Alg 3: separate trailing loop over layers (still layer-major).
@@ -715,7 +735,7 @@ def make_train_step(model, optimizer: Optimizer, exec_cfg: ExecutionConfig,
                     w, g, o = slots
                     nw, no = (packed_update if PK else run_opt)(
                         g, o, w, opt_step)
-                    return None, (ship(_wp.host, nw), ship(_op.host, no))
+                    return None, (ship(_wp, nw), ship(_op, no))
 
                 upd_idle = None
                 if DYN:
@@ -723,8 +743,8 @@ def make_train_step(model, optimizer: Optimizer, exec_cfg: ExecutionConfig,
                         # inactive row: no update — re-ship the incoming
                         # rows so adam's moment decay never touches them
                         w, g, o = slots
-                        return None, (ship(_wp.host, w),
-                                      ship(_op.host, o))
+                        return None, (ship(_wp, w),
+                                      ship(_op, o))
 
                 _, (nw_g, no_g) = relay_scan(
                     upd_body, None, streams,

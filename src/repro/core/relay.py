@@ -54,14 +54,23 @@ Mechanics worth knowing:
   slices of the stacked streams; the ``N mod K`` remainder is a static
   epilogue outside the scan.  The compiled program becomes O(1) in
   depth while staying bit-identical to the unrolled form.
+* Every host<->HBM move goes through ``to_device`` / ``to_host`` under
+  the ``relay.fetch`` / ``relay.writeback`` scopes (``core.spans``), and
+  the scans run under ``relay.scan``.  Inside ``count_bytes`` each move
+  adds its bytes, once for every trip of the scans it runs in: the
+  engine keeps the total per compiled step (``Engine.counters()``).
 """
 from __future__ import annotations
 
+import collections
+import contextlib
+import contextvars
 from typing import Any, Callable, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 
+from repro.core import spans
 from repro.core.eps import Placement
 
 # kernels.relay_copy, imported once per process.  The pallas transport's
@@ -76,6 +85,88 @@ def _relay_copy():
         from repro.kernels import relay_copy
         _RELAY_COPY = relay_copy
     return _RELAY_COPY
+
+
+# ===========================================================================
+# Moves: every host<->HBM transfer of a relay goes through to_device /
+# to_host, under the relay.* scopes, and is counted while a step traces
+# ===========================================================================
+# the bytes of the program being traced, by direction; None outside
+# ``count_bytes``
+_COUNT: contextvars.ContextVar = contextvars.ContextVar("relay_bytes",
+                                                       default=None)
+
+
+@contextlib.contextmanager
+def count_bytes():
+    """Count the relay bytes of the program traced inside the block: a
+    ``Counter`` of ``"fetch"`` (host -> HBM) and ``"writeback"`` (HBM ->
+    host) bytes, each move counted once for every trip of the scans that
+    ``relay_scan`` and ``segment_scan`` run it in.  Only moves through an
+    enabled ``Placement`` count: a no-op placement moves nothing."""
+    count = collections.Counter()
+    token = _COUNT.set(count)
+    try:
+        yield count
+    finally:
+        _COUNT.reset(token)
+
+
+def _counted_into(count, fn: Callable, *args):
+    """``fn`` traced with its moves counted into ``count`` (None leaves
+    them out)."""
+    token = _COUNT.set(count)
+    try:
+        return fn(*args)
+    finally:
+        _COUNT.reset(token)
+
+
+def _tally(direction: str, placement: Placement, tree):
+    count = _COUNT.get()
+    if count is not None and placement.enabled:
+        count[direction] += sum(a.size * a.dtype.itemsize
+                                for a in jax.tree.leaves(tree))
+
+
+def to_device(placement: Placement, tree, grouped: bool = False):
+    """Fetch ``tree`` from the EPS into HBM (``dev_grouped`` for a G-layer
+    slot): the relay's host -> HBM move."""
+    move = (placement.dev_grouped or placement.dev) if grouped \
+        else placement.dev
+    with jax.named_scope(spans.FETCH):
+        _tally("fetch", placement, tree)
+        return move(tree)
+
+
+def to_host(placement: Placement, tree):
+    """Ship ``tree`` from HBM back to the EPS: the relay's HBM -> host
+    move."""
+    with jax.named_scope(spans.WRITEBACK):
+        _tally("writeback", placement, tree)
+        return placement.host(tree)
+
+
+def _scan(body: Callable, init, xs, **kw):
+    """``lax.scan`` over a relay's stops or segments.  The scan's own
+    slicing of ``xs`` and stacking of ``ys`` run under ``spans.SCAN``.
+    The body's moves count once per trip: each trace of the body counts
+    afresh, so a body traced again replaces its count and never adds to
+    it."""
+    trips = jax.tree.leaves(xs)[0].shape[0]
+    outer = _COUNT.get()
+    per_trip = collections.Counter()
+
+    def counted(*args):
+        per_trip.clear()
+        return _counted_into(per_trip, body, *args)
+
+    with jax.named_scope(spans.SCAN):
+        out = jax.lax.scan(body if outer is None else counted, init, xs,
+                           **kw)
+    if outer is not None:
+        outer.update({k: v * trips for k, v in per_trip.items()})
+    return out
 
 
 class Stream(NamedTuple):
@@ -192,29 +283,35 @@ def relay_scan(body: Callable, init, streams: Sequence[Stream], *,
             on = jnp.logical_and(idx >= lo, idx < hi)
             return jax.lax.cond(on,
                                 lambda c: body(c, slots, x),
-                                lambda c: idle_body(c, slots, x),
+                                lambda c: _counted_into(None, idle_body, c,
+                                                        slots, x),
                                 carry)
 
     def fetch(start, size: int):
         """ONE host->HBM copy per stream (per leaf / dtype segment) for a
-        ``size``-layer slot — the only DMA issue site in the repo."""
-        if transport == "pallas":
-            # the copy IS the transfer: rows [start, start+size) of every
-            # leaf/segment move through the double-buffered DMA kernel
-            # (squeezed to the single-layer layout when G == 1, matching
-            # layer_slice below)
-            relay_copy = _relay_copy()
+        ``size``-layer slot."""
+        with jax.named_scope(spans.FETCH):
+            if transport == "pallas":
+                # the copy IS the transfer: rows [start, start+size) of
+                # every leaf/segment move through the double-buffered DMA
+                # kernel (squeezed to the single-layer layout when G == 1,
+                # matching layer_slice below)
+                relay_copy = _relay_copy()
+                slots = tuple(
+                    relay_copy.fetch_slot(s.stacked, start, size,
+                                          squeeze=(G == 1))
+                    for s in streams)
+                for s, slot in zip(streams, slots):
+                    _tally("fetch", s.placement, slot)
+                return slots
+            if G == 1:
+                return tuple(
+                    to_device(s.placement, layer_slice(s.stacked, start))
+                    for s in streams)
             return tuple(
-                relay_copy.fetch_slot(s.stacked, start, size,
-                                      squeeze=(G == 1))
+                to_device(s.placement, group_slice(s.stacked, start, size),
+                          grouped=True)
                 for s in streams)
-        if G == 1:
-            return tuple(s.placement.dev(layer_slice(s.stacked, start))
-                         for s in streams)
-        return tuple(
-            (s.placement.dev_grouped or s.placement.dev)(
-                group_slice(s.stacked, start, size))
-            for s in streams)
 
     def run_stop(carry, slots, start, size: int):
         """Per-layer loop over one fetched G-layer slot (static trips)."""
@@ -247,11 +344,11 @@ def relay_scan(body: Callable, init, streams: Sequence[Stream], *,
             # the top of the consuming iteration
             def stop_body(carry, scan_x):
                 host_slots, x = scan_x
-                slots = tuple(s.placement.dev(t)
+                slots = tuple(to_device(s.placement, t)
                               for s, t in zip(streams, host_slots))
                 return body(carry, slots, x)
 
-            carry, ys_main = jax.lax.scan(
+            carry, ys_main = _scan(
                 stop_body, init, (tuple(s.stacked for s in streams), xs),
                 reverse=reverse, unroll=unroll)
         elif K == 0 and G == 1:
@@ -265,14 +362,14 @@ def relay_scan(body: Callable, init, streams: Sequence[Stream], *,
                 i, x = scan_x
                 return call_body(carry, fetch(i, 1), x, i)
 
-            carry, ys_main = jax.lax.scan(stop_body, init, (idxs, xs),
-                                          reverse=reverse, unroll=unroll)
+            carry, ys_main = _scan(stop_body, init, (idxs, xs),
+                                   reverse=reverse, unroll=unroll)
         elif K == 0:
             def stop_body(carry, i):
                 return run_stop(carry, fetch(i * G, G), i * G, G)
 
-            carry, ys_main = jax.lax.scan(stop_body, init, idxs,
-                                          reverse=reverse, unroll=unroll)
+            carry, ys_main = _scan(stop_body, init, idxs,
+                                   reverse=reverse, unroll=unroll)
         else:
             # K-deep ring: the carry holds the slots for stops i..i+K-1
             # (i-K+1..i reversed); the body consumes ring[0] and issues
@@ -306,7 +403,7 @@ def relay_scan(body: Callable, init, streams: Sequence[Stream], *,
             ring0 = tuple(
                 fetch(min(max(first + step * d, 0), S - 1) * G, G)
                 for d in range(K))
-            (carry, _), ys_main = jax.lax.scan(
+            (carry, _), ys_main = _scan(
                 stop_body, (init, ring0), scan_xs, reverse=reverse,
                 unroll=unroll)
     else:
@@ -395,7 +492,7 @@ def segment_scan(seg_body: Callable, init, *, n_layers: int, every: int,
         carry, ys_rem = seg_body(carry, S * K, R, xs_rem, None)
     ys_scan = None
     if S:
-        carry, ys_scan = jax.lax.scan(
+        carry, ys_scan = _scan(
             scan_body, carry, (jnp.arange(S), xs), reverse=reverse,
             unroll=unroll)
     if not reverse and R:

@@ -36,7 +36,7 @@ import jax
 from repro.checkpoint import io as ckpt_io
 from repro.configs.base import ModelConfig
 from repro.core import baseline as _baseline, decode as _decode, l2l as _l2l
-from repro.core import packing
+from repro.core import packing, relay
 from repro.core.memory_model import MemoryReport, estimate
 from repro.core.schedule import ExecutionConfig
 from repro.engine.placement import placements_for
@@ -81,6 +81,11 @@ class Engine:
         self._placements = placements
         self._donate = donate
         self._fns: dict = {}        # lazily built kernels / jitted wrappers
+        # the relay bytes of each compiled train step, by the batch's
+        # shapes (``relay.count_bytes``), and the run's totals
+        self._step_bytes: dict = {}
+        self._counters = {"train_steps": 0, "relay_fetch_bytes": 0,
+                          "relay_writeback_bytes": 0}
 
     # -- schedule-specific hooks (override in subclasses) -------------------
     def _normalize_cfg(self, exec_cfg: ExecutionConfig) -> ExecutionConfig:
@@ -279,16 +284,12 @@ class Engine:
         if "step_fn" not in self._fns:
             kernel = self._make_step_kernel()
 
-            if self.exec_cfg.dynamic_depth:
-                def step(state: TrainState, batch, n_layers):
+            def step(state: TrainState, batch, *n_layers):
+                with relay.count_bytes() as moved:
                     new_p, new_o, metrics = kernel(
-                        state.params, state.legacy_opt(), batch, n_layers)
-                    return TrainState.from_legacy(new_p, new_o), metrics
-            else:
-                def step(state: TrainState, batch):
-                    new_p, new_o, metrics = kernel(
-                        state.params, state.legacy_opt(), batch)
-                    return TrainState.from_legacy(new_p, new_o), metrics
+                        state.params, state.legacy_opt(), batch, *n_layers)
+                self._step_bytes[_shapes(batch)] = moved
+                return TrainState.from_legacy(new_p, new_o), metrics
 
             self._fns["step_fn"] = step
         return self._fns["step_fn"]
@@ -310,9 +311,23 @@ class Engine:
         n_op = self._depth_operand(n_layers)
         args = (state, batch) if n_op is None else (state, batch, n_op)
         state, metrics = self._fns["train_step"](*args)
+        moved = self._step_bytes[_shapes(batch)]
+        self._counters["train_steps"] += 1
+        self._counters["relay_fetch_bytes"] += moved["fetch"]
+        self._counters["relay_writeback_bytes"] += moved["writeback"]
         if tier is not None:
             state = tier.stage_out(state)
         return state, metrics
+
+    def counters(self) -> dict:
+        """The run's totals since the engine was made: ``train_steps``
+        taken, and the bytes their relay moved host -> HBM
+        (``relay_fetch_bytes``) and HBM -> host
+        (``relay_writeback_bytes``).  Each compiled step's bytes are
+        counted at the relay's move sites while it traces
+        (``core.relay.count_bytes``); a call adds them on the host, with
+        no device sync."""
+        return dict(self._counters)
 
     # -- gradients (no update) ---------------------------------------------
     @property
@@ -434,6 +449,12 @@ class Engine:
         return {"engine": self.name,
                 "arch": self.model.cfg.name,
                 "exec": dataclasses.asdict(self.exec_cfg)}
+
+
+def _shapes(batch) -> tuple:
+    """What a compiled step's relay bytes depend on besides the engine:
+    the shapes and dtypes of the batch."""
+    return tuple((a.shape, str(a.dtype)) for a in jax.tree.leaves(batch))
 
 
 # ===========================================================================

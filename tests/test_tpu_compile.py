@@ -15,6 +15,7 @@ that is handed this file loads the TPU library.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -117,11 +118,11 @@ def test_relay_copy_refused_by_chip_compiler(topo, one_chip):
                      _arg((24, ADAM_SEGMENT), jnp.float32, sh), start)
 
 
-def test_l2l_p_train_step_compile(topo, one_chip, monkeypatch):
+@pytest.fixture(scope="module")
+def l2l_p_step(topo):
     """BERT-Large at full width, l2l-p with the production train settings
-    (``launch.build.make_exec_cfg``): the stacked layers and their Adam
-    slots are host arguments of the compiled step, and only the embedding,
-    the head and their slots are device arguments."""
+    (``launch.build.make_exec_cfg``), compiled once for the described
+    chip: the compiled step and the engine's abstract state."""
     from repro import engine as engines
     from repro.configs.base import get_config
     from repro.core import eps
@@ -131,35 +132,77 @@ def test_l2l_p_train_step_compile(topo, one_chip, monkeypatch):
     dev = topo.devices[0]
     host_sh = SingleDeviceSharding(dev, memory_kind="pinned_host")
     dev_sh = SingleDeviceSharding(dev, memory_kind="device")
-    # the placements ask the backend, which is the CPU here: steer them
-    # to the described chip
-    single = eps.single_device_placement
-    monkeypatch.setattr(eps, "memories_supported", lambda: True)
-    monkeypatch.setattr(eps, "single_device_placement",
-                        lambda device=None: single(dev))
-
-    cfg = get_config("bert-large", "full")
-    ec = ExecutionConfig(n_microbatches=4, offload_stash=True,
-                         weight_stream=True, prefetch_depth=1)
-    eng = engines.create("l2l-p", cfg, ec, donate=False)
-    st = eng.abstract_state()
     put = lambda tree, sh: jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh), tree)
-    state = TrainState(
-        params={**put(st.params, dev_sh),
-                "groups": put(st.params["groups"], host_sh)},
-        opt_state={**put(st.opt_state, dev_sh),
-                   "groups": put(st.opt_state["groups"], host_sh)},
-        step=put(st.step, dev_sh))
     B, S = 32, 512
     batch = {"tokens": _arg((B, S), jnp.int32, dev_sh),
              "targets": _arg((B, S), jnp.int32, dev_sh),
              "mask": _arg((B, S), jnp.float32, dev_sh)}
-    mem = jax.jit(eng.step_fn).lower(state, batch).compile() \
-        .memory_analysis()
+    # the placements ask the backend, which is the CPU here: steer them
+    # to the described chip
+    single = eps.single_device_placement
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(eps, "memories_supported", lambda: True)
+        mp.setattr(eps, "single_device_placement",
+                   lambda device=None: single(dev))
+        ec = ExecutionConfig(n_microbatches=4, offload_stash=True,
+                             weight_stream=True, prefetch_depth=1)
+        eng = engines.create("l2l-p", get_config("bert-large", "full"), ec,
+                             donate=False)
+        st = eng.abstract_state()
+        state = TrainState(
+            params={**put(st.params, dev_sh),
+                    "groups": put(st.params["groups"], host_sh)},
+            opt_state={**put(st.opt_state, dev_sh),
+                       "groups": put(st.opt_state["groups"], host_sh)},
+            step=put(st.step, dev_sh))
+        compiled = jax.jit(eng.step_fn).lower(state, batch).compile()
+    return compiled, st
 
+
+def test_l2l_p_train_step_compile(l2l_p_step):
+    """The stacked layers and their Adam slots are host arguments of the
+    compiled step, and only the embedding, the head and their slots are
+    device arguments."""
+    compiled, st = l2l_p_step
+    mem = compiled.memory_analysis()
     stacked = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(
         (st.params["groups"], st.opt_state["groups"])))
     assert stacked > 3.5e9        # 24 layers of weights, m and v in f32
     assert mem.host_argument_size_in_bytes >= stacked
     assert mem.argument_size_in_bytes < stacked
+
+
+# moves on host memory that the compiler makes with no op_name: the
+# step-end host computations that copy a stacked array host to host, and
+# the ring's first fetch of each stream, a slice of an entry parameter
+# at constant indices that the compiler rebuilds as an async slice
+UNSCOPED_MOVES = (
+    re.compile(r"^%host-call(\.\d+)?\.cloned\.\d+\.call-(start|done) = "
+               r".* custom-call-(start|done)\("),
+    re.compile(r"^%dynamic-slice-start\.\d+ = .* dynamic-slice-start\("
+               r"%state_[\w.]+(, %constant\.\d+)+\), "
+               r"dynamic_slice_sizes=\{[\d,]+\}$"),
+)
+
+
+def test_l2l_p_relay_moves_carry_relay_scopes(l2l_p_step):
+    """Every async move on host memory (``S(5)``) is a relay move by its
+    scope, ``relay.fetch`` or ``relay.writeback``, or one the compiler
+    made with no scope (``UNSCOPED_MOVES``)."""
+    from repro.core import spans
+    moves, unscoped = {spans.FETCH: 0, spans.WRITEBACK: 0}, []
+    for line in l2l_p_step[0].as_text().splitlines():
+        line = line.strip()
+        if "S(5)" not in line or not re.search(r" [\w-]+-(start|done)\(",
+                                               line):
+            continue
+        name = re.search(r'op_name="([^"]*)"', line)
+        if name is None:
+            unscoped.append(line)
+            continue
+        assert spans.phase(name.group(1)) in moves, line
+        moves[spans.phase(name.group(1))] += 1
+    assert moves[spans.FETCH] and moves[spans.WRITEBACK]
+    assert all(any(p.match(ln) for p in UNSCOPED_MOVES)
+               for ln in unscoped), unscoped
