@@ -33,7 +33,7 @@ sharding (memory-kind-only transfers still re-state the spec).
 """
 from __future__ import annotations
 
-from typing import Callable, NamedTuple, Optional
+from typing import Any, Callable, NamedTuple, Optional
 
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
@@ -44,6 +44,7 @@ class Placement(NamedTuple):
     dev: Callable                            # tree -> tree (device HBM)
     dev_grouped: Optional[Callable] = None   # G-layer slot -> device HBM
     enabled: bool = True
+    device: Any = None                       # the one device, if one
 
 
 def noop_placement() -> Placement:
@@ -75,8 +76,8 @@ def single_device_placement(device=None) -> Placement:
     def to(tree, sh):
         return jax.tree.map(lambda a: jax.device_put(a, sh), tree)
 
-    dev = lambda t: to(t, d)
-    return Placement(lambda t: to(t, h), dev, dev)
+    move = lambda t: to(t, d)
+    return Placement(lambda t: to(t, h), move, move, device=dev)
 
 
 def mesh_placement(mesh, pspec_tree) -> Placement:

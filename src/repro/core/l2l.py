@@ -396,18 +396,19 @@ def make_train_step(model, optimizer: Optimizer, exec_cfg: ExecutionConfig,
                 if has_mem else None)
 
             @jax.named_scope(spans.BACKWARD)
-            def bwd_body(core, slots, stash_l, _g=group, _ctx=ctx,
+            def bwd_body(core, slots, _x, _g=group, _ctx=ctx,
                          _mem=mem_ub, _wp=wp, _op=op, _has_mem=has_mem):
                 """Recompute-vjp microbatch loop (+ eager opt) of one
-                layer; the slots are already the HBM-resident slices.
-                With pack_params the vjp differentiates the UNPACKED view
-                and every gradient-side reduction below stays on the tree,
-                so the packed schedule's math is bit-identical."""
-                w_dev = slots[0]
-                opt_l = slots[1] if len(slots) > 1 else None
+                layer; the slots (weights, the optimizer's with the eager
+                update, the stashed boundary last) are already the
+                HBM-resident slices.  With pack_params the vjp
+                differentiates the UNPACKED view and every gradient-side
+                reduction below stays on the tree, so the packed
+                schedule's math is bit-identical."""
+                w_dev, stash_dev = slots[0], slots[-1]
+                opt_l = slots[1] if len(slots) > 2 else None
                 dx_c, dmem_c, gn_c, nf_c = core
                 w_tree = packing.unpack(w_dev) if PK else w_dev
-                stash_dev = to_device(placements.stash, stash_l)
 
                 def ub_body(dw_acc, args):
                     if _has_mem:
@@ -458,6 +459,12 @@ def make_train_step(model, optimizer: Optimizer, exec_cfg: ExecutionConfig,
                             new_opt = jax.tree.map(
                                 lambda n, o: jnp.where(finite_l, n, o),
                                 new_opt, opt_l)
+                    # the next layer's vjp waits for this update: the
+                    # ring refills this layer's slot once the update has
+                    # read it, and left free the scheduler defers the
+                    # update, and the refill with it, past that vjp
+                    (new_w, new_opt), dxin_ub = jax.lax.optimization_barrier(
+                        ((new_w, new_opt), dxin_ub))
                     out = (ship(_wp, new_w), ship(_op, new_opt))
                 else:
                     # Alg 3: gradients are shipped to the EPS (host) and the
@@ -473,7 +480,7 @@ def make_train_step(model, optimizer: Optimizer, exec_cfg: ExecutionConfig,
 
             bwd_idle = None
             if DYN:
-                def bwd_idle(core, slots, _stash, _wp=wp, _op=op):
+                def bwd_idle(core, slots, _x, _wp=wp, _op=op):
                     """Inactive layer: the carry (dx, dmem, gnorm,
                     nonfinite) passes through untouched; the write-back
                     ys re-ship the incoming rows (eager: the row's
@@ -498,8 +505,10 @@ def make_train_step(model, optimizer: Optimizer, exec_cfg: ExecutionConfig,
                     # consumed only after the scan — it overlaps the next
                     # backward.
                     streams.append(Stream(op, opt_state["groups"][gi]))
+                # the stashed boundaries ride the ring too, a stop ahead
+                streams.append(Stream(placements.stash, stashes[gi]))
                 core0, outs = relay_scan(
-                    bwd_body, core0, streams, xs=stashes[gi], reverse=True,
+                    bwd_body, core0, streams, reverse=True,
                     group=G, prefetch=PF, unroll=UNROLL, transport=TR,
                     active=act_win, idle_body=bwd_idle)
             else:
@@ -565,8 +574,10 @@ def make_train_step(model, optimizer: Optimizer, exec_cfg: ExecutionConfig,
                         if exec_cfg.eager_optimizer:
                             seg_streams.append(Stream(op, _seg_slice(
                                 opt_state["groups"][gi], s0, s1)))
+                        seg_streams.append(Stream(placements.stash,
+                                                  seg_stash))
                         core0, outs_segs[si] = relay_scan(
-                            bwd_body, core0, seg_streams, xs=seg_stash,
+                            bwd_body, core0, seg_streams,
                             reverse=True, group=G, prefetch=PF,
                             unroll=UNROLL, transport=TR)
                     # per-segment write-backs concatenate to the (N, ...)
@@ -621,8 +632,10 @@ def make_train_step(model, optimizer: Optimizer, exec_cfg: ExecutionConfig,
                         if exec_cfg.eager_optimizer:
                             seg_streams.append(Stream(
                                 _op, group_slice(_o, s0, size)))
+                        seg_streams.append(Stream(placements.stash,
+                                                  seg_stash))
                         return relay_scan(
-                            bwd_body, core, seg_streams, xs=seg_stash,
+                            bwd_body, core, seg_streams,
                             reverse=True, group=G, prefetch=PF,
                             unroll=UNROLL, transport=TR, active=win,
                             idle_body=bwd_idle)

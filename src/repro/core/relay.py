@@ -10,7 +10,14 @@ Alg-3 trailing update, prefill, serve-decode) is the same composition:
   the old two-slot double buffer: the DMA for stop ``i + k`` is issued at
   the top of stop ``i``'s body, so up to ``k`` transfers are in flight
   while one slot computes (``prefetch_depth = 0`` keeps the historical
-  fetch-inside-the-iteration schedule),
+  fetch-inside-the-iteration schedule).  The fetch lands in the slot that
+  stop ``i - 1`` has finished with, never in the one stop ``i`` computes
+  on, so it overlaps all of stop ``i`` (in the backward: the vjp and the
+  optimizer update that reads the slot last).  A while loop keeps each
+  carried value in one buffer, so the slots are kept apart by the loop's
+  shape: one trip runs ``k + 1`` stops (``_ring_scan``).  Folding them
+  back into one carried slot that a stop both reads and refills makes
+  every fetch wait for that stop's last read.
 * **layer groups** — ``layers_per_relay = G`` relays G stacked layers per
   stop: ONE dynamic-slice + ``device_put`` per stream covers G layers
   (one copy per leaf, or one per dtype segment when packed), and the body
@@ -33,15 +40,18 @@ Mechanics worth knowing:
   fetch.  With G = 1 there is never a remainder and the emitted program
   is exactly the historical per-layer scan.
 * Nothing blocks inside jit: fetches are plain ``jax.device_put`` whose
-  results are consumed one-or-more iterations later through the scan
-  carry, so XLA's latency-hiding scheduler keeps ring copies in flight
-  while the current slot computes.  On backends that drop memory-space
-  transfers (CPU — see ``eps.memories_supported``) the restructured scan
-  computes identical results with no-op moves.
+  results are consumed one or more stops later, so XLA's latency-hiding
+  scheduler keeps ring copies in flight while the current slot
+  computes; each fetch lands by the end of the stop that issued it.  On
+  backends that drop memory-space transfers (CPU — see
+  ``eps.memories_supported``) the restructured scan computes identical
+  results with no-op moves.
 * ``ys`` keep layer order: a reverse scan stacks a layer's outputs at its
-  forward index (matching ``lax.scan(reverse=True)`` semantics), and
-  grouped stops stack their G per-layer outputs in forward order before
-  the scan stacks the stops.
+  forward index (matching ``lax.scan(reverse=True)`` semantics).  Without
+  a ring, grouped stops stack their G per-layer outputs in forward order
+  before the scan stacks the stops; the ring writes each layer's outputs
+  at its index into ``(N, ...)`` buffers it carries, and slices ``xs`` the
+  same way, so no host-memory array is reshaped.
 * ``active=(lo, hi)`` + ``idle_body`` gate each stop with a traced layer
   window: stops outside it run the idle body (pass activations through,
   re-ship slots so inactive rows stay bit-frozen).  This is how
@@ -69,6 +79,11 @@ from typing import Any, Callable, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+
+# the scalar ``lax.scan`` broadcasts its ys buffers from, in a memory
+# space; JAX has no public form of it yet
+from jax._src.lax.lax import empty2 as _empty
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from repro.core import spans
 from repro.core.eps import Placement
@@ -261,6 +276,10 @@ def relay_scan(body: Callable, init, streams: Sequence[Stream], *,
     compiled program serves every window value — the mechanism behind
     ``ExecutionConfig.dynamic_depth``.  ``active=None`` (the default)
     emits the historical ungated program unchanged.
+
+    ``prefetch = K >= 1`` runs the K-deep ring (``_ring_scan``): each stop
+    fetches the stop K ahead into a slot no value of its own occupies.
+    ``prefetch = 0`` fetches each stop's slot at the top of its iteration.
     """
     streams = tuple(streams)
     assert streams, "relay_scan needs at least one stream"
@@ -313,8 +332,9 @@ def relay_scan(body: Callable, init, streams: Sequence[Stream], *,
                           grouped=True)
                 for s in streams)
 
-    def run_stop(carry, slots, start, size: int):
-        """Per-layer loop over one fetched G-layer slot (static trips)."""
+    def run_layers(carry, slots, start, size: int):
+        """Per-layer loop over one fetched G-layer slot (static trips):
+        the carry and the per-layer ys, in layer order."""
         x_stop = None if xs is None else group_slice(xs, start, size)
         ys = [None] * size
         order = range(size - 1, -1, -1) if reverse else range(size)
@@ -322,6 +342,10 @@ def relay_scan(body: Callable, init, streams: Sequence[Stream], *,
             slot_j = tuple(_index(s, j) for s in slots)
             x_j = None if x_stop is None else _index(x_stop, j)
             carry, ys[j] = call_body(carry, slot_j, x_j, start + j)
+        return carry, ys
+
+    def run_stop(carry, slots, start, size: int):
+        carry, ys = run_layers(carry, slots, start, size)
         if all(y is None for y in ys):
             return carry, None
         return carry, _stack(ys)
@@ -329,6 +353,11 @@ def relay_scan(body: Callable, init, streams: Sequence[Stream], *,
     def run_remainder(carry):
         return run_stop(carry, fetch(S * G, R), S * G, R)
 
+    if K and S:
+        return _ring_scan(body, call_body, run_layers, fetch, init, streams,
+                          xs, n, G, K, reverse, unroll)
+
+    # no ring: each stop fetches its own slot at the top of its iteration
     ys_rem = None
     if reverse and R:
         # reverse execution visits the trailing short stop first
@@ -338,7 +367,7 @@ def relay_scan(body: Callable, init, streams: Sequence[Stream], *,
     ys_main = None
     if S > 0:
         idxs = jnp.arange(S)
-        if K == 0 and G == 1 and transport == "xla" and active is None:
+        if G == 1 and transport == "xla" and active is None:
             # historical per-layer scan, reproduced exactly: streams and
             # xs ride the scan's native xs slicing; the fetch happens at
             # the top of the consuming iteration
@@ -351,7 +380,7 @@ def relay_scan(body: Callable, init, streams: Sequence[Stream], *,
             carry, ys_main = _scan(
                 stop_body, init, (tuple(s.stacked for s in streams), xs),
                 reverse=reverse, unroll=unroll)
-        elif K == 0 and G == 1:
+        elif G == 1:
             # pallas transport can't ride the scan's native xs slicing —
             # the DMA kernel must issue the copy itself, so the stop
             # index drives an explicit per-layer fetch (same schedule:
@@ -364,48 +393,12 @@ def relay_scan(body: Callable, init, streams: Sequence[Stream], *,
 
             carry, ys_main = _scan(stop_body, init, (idxs, xs),
                                    reverse=reverse, unroll=unroll)
-        elif K == 0:
+        else:
             def stop_body(carry, i):
                 return run_stop(carry, fetch(i * G, G), i * G, G)
 
             carry, ys_main = _scan(stop_body, init, idxs,
                                    reverse=reverse, unroll=unroll)
-        else:
-            # K-deep ring: the carry holds the slots for stops i..i+K-1
-            # (i-K+1..i reversed); the body consumes ring[0] and issues
-            # the DMA for stop i+K (i-K) before the per-layer loop, so up
-            # to K transfers overlap compute.  Edge iterations re-fetch a
-            # clamped edge stop; those copies are dropped.
-            def nxt(i):
-                return (jnp.maximum(i - K, 0) if reverse
-                        else jnp.minimum(i + K, S - 1))
-
-            if G == 1:
-                # per-layer xs still ride the scan's native slicing
-                def stop_body(carry_ring, scan_x):
-                    i, x = scan_x
-                    carry, ring = carry_ring
-                    fetched = fetch(nxt(i) * G, G)
-                    carry, ys = call_body(carry, ring[0], x, i)
-                    return (carry, ring[1:] + (fetched,)), ys
-
-                scan_xs = (idxs, xs)
-            else:
-                def stop_body(carry_ring, i):
-                    carry, ring = carry_ring
-                    fetched = fetch(nxt(i) * G, G)
-                    carry, ys = run_stop(carry, ring[0], i * G, G)
-                    return (carry, ring[1:] + (fetched,)), ys
-
-                scan_xs = idxs
-
-            first, step = (S - 1, -1) if reverse else (0, 1)
-            ring0 = tuple(
-                fetch(min(max(first + step * d, 0), S - 1) * G, G)
-                for d in range(K))
-            (carry, _), ys_main = _scan(
-                stop_body, (init, ring0), scan_xs, reverse=reverse,
-                unroll=unroll)
     else:
         carry = init
 
@@ -413,6 +406,119 @@ def relay_scan(body: Callable, init, streams: Sequence[Stream], *,
         carry, ys_rem = run_remainder(carry)
 
     return carry, _combine_ys(ys_main, ys_rem, S, G)
+
+
+def _ring_scan(body, call_body, run_layers, fetch, init, streams, xs,
+               n: int, G: int, K: int, reverse: bool, unroll):
+    """``relay_scan``'s K-deep prefetch ring (``prefetch = K >= 1``).
+
+    The ring holds K + 1 slots, and the fetch for walk position p + K
+    lands in the one that walk position p - 1 has finished with, never in
+    the slot stop p computes on.  A while loop carries each value in one
+    buffer, so the slots are kept apart by the loop's shape: one scan
+    trip runs K + 1 consecutive stops.  The carry holds the K slots the
+    trip starts with, each refilled by the stop after the one that reads
+    it; the stop that reads the K + 1-th slot finds it in a value of the
+    trip's own, fetched at the trip's first stop.  Folding the ring back
+    into one carried slot that is read and refilled in the same stop
+    makes every fetch wait for the last read of its stop, the optimizer
+    update in the backward.
+
+    A stop reads its slice of ``xs`` and writes its ys at its layer
+    index (``ys`` are (n, ...) buffers in the carry), so no stacked
+    host-memory array is reshaped.  The ``S mod (K + 1)`` stops the full
+    trips leave run after the scan on the slots the ring ends with and
+    fetch nothing; a fetch in the scan past the walk's end re-fetches its
+    last stop (clamped) and is dropped.
+    """
+    S, R = divmod(n, G)
+    P = K + 1
+    T, r = divmod(S, P)
+
+    def stop_at(p):
+        """The stop at walk position ``p``."""
+        return S - 1 - p if reverse else p
+
+    def one_layer():
+        slots = fetch(0, G)
+        if G > 1:
+            slots = tuple(_index(s, 0) for s in slots)
+        x = None if xs is None else layer_slice(xs, 0)
+        return body(init, slots, x)[1]
+
+    jaxpr, shapes = _counted_into(
+        None, jax.make_jaxpr(one_layer, return_shape=True))
+
+    def buffer(aval):
+        # what ``lax.scan`` builds its own ys from: a broadcast scalar in
+        # the ys' memory space, which the compiler allocates in place (a
+        # ``device_put`` of zeros would fill HBM and copy it to the host)
+        return jax.lax.broadcast(
+            _empty(aval.dtype, memory_space=aval.memory_space),
+            (n,) + aval.shape)
+
+    bufs = jax.tree.unflatten(jax.tree.structure(shapes),
+                              [buffer(a) for a in jaxpr.out_avals])
+
+    # a stacked host array the ring writes keeps the device's default
+    # layout, the one the EPS holds between steps; left free, it takes the
+    # layout of the rows written into it, and the step ends by copying it
+    dev = next((s.placement.device for s in streams
+                if s.placement.device is not None), None)
+
+    def settle(buf):
+        if (dev is None
+                or jax.typeof(buf).memory_space != jax.memory.Space.Host):
+            return buf
+        return with_layout_constraint(buf, Layout.from_pjrt_layout(
+            dev.client.get_default_layout(buf.dtype, buf.shape, dev)))
+
+    def put(bufs, ys, start):
+        for j, y in enumerate(ys):
+            bufs = jax.tree.map(
+                lambda b, v, i=start + j: settle(
+                    jax.lax.dynamic_update_index_in_dim(b, v, i, 0)),
+                bufs, y)
+        return bufs
+
+    def run(carry, bufs, slots, stop):
+        if G == 1:
+            x = None if xs is None else layer_slice(xs, stop)
+            carry, y = call_body(carry, slots, x, stop)
+            return carry, put(bufs, [y], stop)
+        carry, ys = run_layers(carry, slots, stop * G, G)
+        return carry, put(bufs, ys, stop * G)
+
+    def run_remainder(carry, bufs):
+        carry, ys = run_layers(carry, fetch(S * G, R), S * G, R)
+        return carry, put(bufs, ys, S * G)
+
+    carry = init
+    if reverse and R:
+        carry, bufs = run_remainder(carry, bufs)
+    ring = tuple(fetch(stop_at(p) * G, G) for p in range(min(K, S)))
+    if T:
+        def trip(state, t):
+            carry, held, bufs = state
+            held = list(held) + [None]
+            for j in range(P):
+                p = t * P + j
+                fetched = fetch(stop_at(jnp.minimum(p + K, S - 1)) * G, G)
+                carry, bufs = run(carry, bufs, held[j], stop_at(p))
+                # the fetch lands by the end of the stop that issued it,
+                # in the slot the stop before has finished with (the
+                # trip's first stop: the trip's own K + 1-th slot)
+                carry, held[j - 1] = jax.lax.optimization_barrier(
+                    (carry, fetched))
+            return (carry, tuple(held[:K]), bufs), None
+
+        (carry, ring, bufs), _ = _scan(trip, (carry, ring, bufs),
+                                       jnp.arange(T), unroll=unroll)
+    for j in range(r):
+        carry, bufs = run(carry, bufs, ring[j], stop_at(T * P + j))
+    if not reverse and R:
+        carry, bufs = run_remainder(carry, bufs)
+    return carry, bufs
 
 
 def _combine_ys(ys_main, ys_rem, n_full_stops: int, group: int):

@@ -38,12 +38,14 @@ def test_prefetch_grads_bit_identical(name, make_engine):
     batch = make_batch(cfg, 4, 16)
     params = LayeredModel(cfg).init_params(jax.random.PRNGKey(0))
     outs = {}
-    for pf in (0, 1):
+    for pf in (0, 1, 2):
         eng = make_engine(name, exec_cfg=ExecutionConfig(
             n_microbatches=2, prefetch_depth=pf))
         outs[pf] = eng.grads(params, batch)
-    assert float(outs[0][0]) == float(outs[1][0])
-    _assert_trees_bitwise(outs[0][1], outs[1][1], f"{name} grads")
+    for pf in (1, 2):
+        assert float(outs[0][0]) == float(outs[pf][0])
+        _assert_trees_bitwise(outs[0][1], outs[pf][1],
+                              f"{name} grads, prefetch {pf}")
 
 
 @pytest.mark.parametrize("name", ["l2l", "l2l-p"])
@@ -53,17 +55,19 @@ def test_prefetch_updates_bit_identical(name, make_engine):
     cfg = _cfg()
     batch = make_batch(cfg, 4, 16)
     states = {}
-    for pf in (0, 1):
+    for pf in (0, 1, 2):
         eng = make_engine(name, optimizer=adam(lr=1e-3),
                           exec_cfg=ExecutionConfig(n_microbatches=2,
                                                    prefetch_depth=pf))
         state, m = eng.train_step(eng.init(jax.random.PRNGKey(0)), batch)
         states[pf] = (state, float(m["loss"]))
-    assert states[0][1] == states[1][1]
-    _assert_trees_bitwise(states[0][0].params, states[1][0].params,
-                          f"{name} params")
-    _assert_trees_bitwise(states[0][0].opt_state, states[1][0].opt_state,
-                          f"{name} opt state")
+    for pf in (1, 2):
+        assert states[0][1] == states[pf][1]
+        _assert_trees_bitwise(states[0][0].params, states[pf][0].params,
+                              f"{name} params, prefetch {pf}")
+        _assert_trees_bitwise(states[0][0].opt_state,
+                              states[pf][0].opt_state,
+                              f"{name} opt state, prefetch {pf}")
 
 
 def test_prefetch_covers_multi_group_and_mem_archs(make_engine):

@@ -48,7 +48,8 @@ def _assert_trees_bitwise(a, b, what):
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("group,prefetch,reverse", [
     (1, 0, False), (1, 2, True), (2, 0, False), (2, 1, True),
-    (3, 2, False), (3, 1, True), (7, 1, False), (5, 0, True)])
+    (3, 2, False), (3, 1, True), (7, 1, False), (5, 0, True),
+    (1, 1, False), (1, 1, True), (1, 2, False), (2, 2, True)])
 def test_relay_scan_visits_layers_in_order(group, prefetch, reverse):
     """Bodies run per layer, in direction order, and ys keep layer order
     regardless of grouping/prefetch/remainder handling."""
@@ -79,13 +80,51 @@ def test_relay_scan_reverse_carry_order():
         return carry * 10.0 + slots[0], None
 
     ref = None
-    for g, k in [(1, 0), (2, 1), (3, 2), (2, 2)]:
+    for g, k in [(1, 0), (2, 1), (3, 2), (2, 2), (1, 1), (1, 2)]:
         out, _ = jax.jit(lambda g=g, k=k: relay.relay_scan(
             body, jnp.float32(0.0),
             (relay.Stream(noop_placement(), stacked),),
             reverse=True, group=g, prefetch=k))()
         ref = out if ref is None else ref
         assert float(out) == float(ref) == 54321.0
+
+
+@pytest.mark.parametrize("n,group,prefetch,reverse", [
+    (4, 1, 1, False), (4, 1, 1, True), (5, 1, 1, True), (6, 1, 2, False),
+    (6, 1, 2, True), (5, 1, 2, True), (2, 1, 2, False), (7, 2, 1, True),
+    (9, 2, 2, False)])
+def test_relay_ring_bit_identical_to_plain_scan(n, group, prefetch,
+                                                reverse):
+    """The prefetch ring with several streams (weights, optimizer slots
+    and a stash-like row relayed a stop ahead), device xs and per-layer
+    ys computes what the plain per-layer scan computes, bit for bit:
+    depths the ring's trips divide and depths they leave stops over."""
+    # small whole numbers: every sum and product below is exact, so any
+    # slot, row or order mix-up shows as a different number
+    def ints(shape, seed):
+        return jax.random.randint(jax.random.PRNGKey(seed), shape, -3,
+                                  4).astype(jnp.float32)
+
+    w = {"a": ints((n, 4, 4), 1), "b": ints((n, 4), 2)}
+    opt = ints((n, 2, 4), 3)
+    stash = ints((n, 4), 4)
+    xs = jnp.arange(n, dtype=jnp.float32)
+
+    def body(carry, slots, x):
+        wl, ol, sl = slots
+        h = carry * 3.0 + wl["a"].sum(0) + wl["b"] + sl * x
+        return h, (ol * 2.0 + h, {"h": h - x})
+
+    def run(G, K):
+        streams = tuple(relay.Stream(noop_placement(), t)
+                        for t in (w, opt, stash))
+        return jax.jit(lambda: relay.relay_scan(
+            body, jnp.ones((4,)), streams, xs=xs, reverse=reverse,
+            group=G, prefetch=K))()
+
+    want = run(1, 0)
+    got = run(group, prefetch)
+    _assert_trees_bitwise(got, want, f"n={n} G={group} k={prefetch}")
 
 
 def test_n_stops():

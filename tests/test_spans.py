@@ -41,10 +41,18 @@ def _bytes(tree) -> int:
 # (settings, fetches and write-backs per step in units of one layer's
 # weights W, its Adam slots O and one stashed boundary A, for N = 4)
 CASES = {
-    # ring of one: each pass fetches its N stops and re-fetches the
-    # clamped edge stop once
+    # ring of one: each pass fetches its N stops (the backward also the
+    # stashed boundaries, a stop ahead) and re-fetches the clamped edge
+    # stop once
     "prefetch1-g1": (dict(prefetch_depth=1),
-                     dict(W=(N + 1) + (N + 1), O=N + 1, A=N),
+                     dict(W=(N + 1) + (N + 1), O=N + 1, A=N + 1),
+                     dict(W=N, O=N, A=N)),
+    # ring of two: a trip runs 3 stops, so one trip covers stops 0-2 and
+    # re-fetches the edge once (its fetches target stops 2, 3 and the
+    # clamped 4); stop 3 runs after the scan on the slot the ring ends
+    # with and fetches nothing
+    "prefetch2-g1": (dict(prefetch_depth=2),
+                     dict(W=(N + 1) + (N + 1), O=N + 1, A=N + 1),
                      dict(W=N, O=N, A=N)),
     # two layers a stop, no ring: each pass fetches every layer once
     "prefetch0-g2": (dict(layers_per_relay=2),
@@ -52,10 +60,11 @@ CASES = {
                      dict(W=N, O=N, A=N)),
     # two segments of two: the forward fetches 3 W a segment (2 stops and
     # the edge) and ships the segment's entry; the backward fetches the
-    # entry, 2 W to recompute the one missing boundary, ships it, fetches
-    # both boundaries and 3 (W + O) for the segment's vjp
+    # entry, 1 W to recompute the one missing boundary (a one-stop ring
+    # has no edge to re-fetch), ships it, and fetches 3 (W + O + A) for
+    # the segment's vjp (2 stops and the edge)
     "stash2": (dict(prefetch_depth=1, stash_every=2),
-               dict(W=2 * 3 + 2 * 2 + 2 * 3, O=2 * 3, A=2 * (1 + 2)),
+               dict(W=2 * 3 + 2 * 1 + 2 * 3, O=2 * 3, A=2 * (1 + 3)),
                dict(W=N, O=N, A=2 + 2)),
 }
 

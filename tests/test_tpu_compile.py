@@ -175,13 +175,15 @@ def test_l2l_p_train_step_compile(l2l_p_step):
 
 # moves on host memory that the compiler makes with no op_name: the
 # step-end host computations that copy a stacked array host to host, and
-# the ring's first fetch of each stream, a slice of an entry parameter
-# at constant indices that the compiler rebuilds as an async slice
+# the ring's first fetch of each stream, a slice at constant indices that
+# the compiler rebuilds as an async slice: of an entry parameter, or of
+# the stacked boundaries the forward scan returns (the backward's stash)
 UNSCOPED_MOVES = (
     re.compile(r"^%host-call(\.\d+)?\.cloned\.\d+\.call-(start|done) = "
                r".* custom-call-(start|done)\("),
     re.compile(r"^%dynamic-slice-start\.\d+ = .* dynamic-slice-start\("
-               r"%state_[\w.]+(, %constant\.\d+)+\), "
+               r"%(state_[\w.]+|get-tuple-element\.\d+)"
+               r"(, (/\*index=\d+\*/)?%constant\.\d+)+\), "
                r"dynamic_slice_sizes=\{[\d,]+\}$"),
 )
 
@@ -206,3 +208,62 @@ def test_l2l_p_relay_moves_carry_relay_scopes(l2l_p_step):
     assert moves[spans.FETCH] and moves[spans.WRITEBACK]
     assert all(any(p.match(ln) for p in UNSCOPED_MOVES)
                for ln in unscoped), unscoped
+
+
+def _computations(hlo: str) -> dict:
+    """name -> instruction lines of each computation of ``as_text()``."""
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{$", line)
+        if head and not line.startswith(" "):
+            cur = comps.setdefault(head.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            cur.append(line.strip())
+    return comps
+
+
+def test_l2l_p_backward_fetches_overlap_the_vjp(l2l_p_step):
+    """In the backward relay's body, each stop issues the fetch of the
+    stop K ahead (weights, Adam slots and stashed boundary, on ``S(5)``)
+    before its microbatch vjp loop: the fetch lands in a slot that no
+    value of the stop occupies, so neither the vjp nor the optimizer
+    update that reads the slot last holds it back.  A trip runs K + 1
+    stops, so before the j-th vjp loop of the body the next-stop fetches
+    of j + 1 stops have been issued.  Checked for the transfers of 16 MiB
+    and more, which carry most of a stop's bytes (the MLP's weights and
+    their Adam slots, 100.7 of 151 MB, and the 33.6 MB boundary); the
+    compiler issues the smaller ones where it sees fit."""
+    from repro.core import spans
+
+    def vjp_loop(line):
+        name = re.search(r'op_name="([^"]*)"', line)
+        return (" while(" in line and name is not None
+                and spans.phase(name.group(1)) == spans.BACKWARD)
+
+    def fetched_bytes(line):
+        out = re.search(r"\), (\w+)\[([\d,]*)\]", line)
+        n = 1
+        for d in filter(None, out.group(2).split(",")):
+            n *= int(d)
+        return n * jnp.dtype(out.group(1).replace("bf16", "bfloat16")
+                             .replace("f32", "float32")).itemsize
+
+    bodies = [lines for lines in
+              _computations(l2l_p_step[0].as_text()).values()
+              if any(vjp_loop(ln) for ln in lines)]
+    assert len(bodies) == 1, len(bodies)
+    (body,) = bodies
+    loops = [i for i, ln in enumerate(body) if vjp_loop(ln)]
+    fetches = [(i, fetched_bytes(ln)) for i, ln in enumerate(body)
+               if re.search(r"dynamic-slice-start\(", ln) and "S(5)" in ln]
+    # every stop fetches 16 leaves of BERT-Large's layer, each with two
+    # Adam slots, and the stashed boundary
+    assert len(fetches) == len(loops) * (16 * 3 + 1)
+    big = [i for i, b in fetches if b >= 16 * 2**20]
+    per_stop = len(big) // len(loops)
+    assert per_stop == 2 * 3 + 1
+    for j, loop in enumerate(loops):
+        issued = sum(1 for i in big if i < loop)
+        assert issued >= (j + 1) * per_stop, (j, issued)
