@@ -50,11 +50,10 @@ def main(argv=None) -> int:
     runner.describe_host(dev)
     runner.enable_compile_cache()
     conf, traffic = c["config"], c["traffic"]
-    cc, opt = conf["config"], conf["optimizer"]
     B, S = traffic["batch"], traffic["seq"]
-    refs = {"reference": reference.Reference(cc, opt, B, S),
-            "control": reference.Reference(cc, opt, B, S, "fp8"),
-            "half_batch": reference.Reference(cc, opt, B // 2, S)}
+    refs = {"reference": reference.Reference(conf, B, S),
+            "control": reference.Reference(conf, B, S, "fp8"),
+            "half_batch": reference.Reference(conf, B // 2, S)}
     sink = open(a.out, "a") if a.out else None
     for n, seed in enumerate(int(x) for x in a.seeds.split(",")):
         t = time.perf_counter()
@@ -69,8 +68,7 @@ def main(argv=None) -> int:
             t = time.perf_counter()
             batches = (s["checked"] if name != "half_batch"
                        else [half(b) for b in s["checked"]])
-            found[name] = reference.train(cc, opt, s["key"], batches,
-                                          ref=ref)
+            found[name] = reference.train(conf, s["key"], batches, ref=ref)
             found[name]["seconds"] = time.perf_counter() - t
         want = found["reference"]
         rows = [{"reading": "program", "gaps": check.gaps(s["got"], want),

@@ -6,12 +6,15 @@ or per-layer metric sits in a file of its own under ``chipbench/``:
 
 * ``workloads/<cell>.json``: its configuration, traffic, why, and the
   limits of its correctness check;
-* ``configs/<config>.json``: the configuration as run, with its source;
+* ``configs/<config>.json``: the configuration as run, with its source,
+  the block family it is built from (``"reference"``: a module under
+  ``chipbench/``, such as ``reference/decoder.py``) and its CPU test
+  sizes (``"smoke"``);
 * ``traffic/<traffic>.json``: batch, sequence, batches cycled, documents;
 * ``metrics/<metric>.py``: the reader of one per-layer metric.
 
-So a later change adds a cell, a configuration or a metric by adding
-files and entries, without editing any file here.
+So a later change adds a cell, a configuration, a block family or a
+metric by adding files and entries, without editing any file here.
 """
 from __future__ import annotations
 
@@ -47,10 +50,22 @@ def load(name: str) -> dict:
     }
 
 
-def metric_reader(name: str):
-    """The ``read`` function of ``metrics/<name>.py``."""
-    spec = importlib.util.spec_from_file_location(
-        f"chipbench_metric_{name}", HERE / "metrics" / f"{name}.py")
+def _module(path: Path, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def metric_reader(name: str):
+    """The ``read`` function of ``metrics/<name>.py``."""
+    return _module(HERE / "metrics" / f"{name}.py",
+                   f"chipbench_metric_{name}").read
+
+
+def family(conf: dict):
+    """The block family module that configuration file ``conf`` names
+    (``"reference"``, a path under ``chipbench/``): see
+    ``reference/model.py`` for what it gives."""
+    path = HERE / conf["reference"]
+    return _module(path, f"chipbench_family_{path.stem}")

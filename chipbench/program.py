@@ -5,11 +5,11 @@ readings the check takes from its state.
 ``repro.engine.create("l2l-p", cfg, make_exec_cfg(shape, cfg, mesh),
 optimizer=adam(lr))`` with the production relay settings.  The readings
 name each leaf as the reference does: ``embed.tok``, ``head.ln_f.scale``,
-``layers.<i>.attn.wq`` (one entry per row of a stacked layer array).
+``layers.<i>.attn.wq`` (one entry per row of a stacked layer array; ``i``
+counts the layers of the model, through its layer groups in order).
 """
 from __future__ import annotations
 
-import functools
 import sys
 from pathlib import Path
 
@@ -18,19 +18,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import SingleDeviceSharding
 
+from chipbench import cell
 from chipbench.reference import model as M
 
 ROOT = Path(__file__).resolve().parents[1]
-
-# what the configuration file says, against the program's field for it
-CONFIG_FIELDS = {
-    "hidden_size": "d_model", "num_hidden_layers": "n_layers",
-    "num_attention_heads": "n_heads", "num_key_value_heads": "n_kv_heads",
-    "head_dim": "d_head", "intermediate_size": "d_ff",
-    "vocab_size": "vocab_size", "rope_theta": "rope_theta",
-    "tie_word_embeddings": "tie_embeddings", "attention_bias": "qkv_bias",
-    "compute_dtype": "dtype", "param_dtype": "param_dtype",
-}
 
 
 def import_program():
@@ -46,18 +37,16 @@ def import_program():
 
 def program_config(conf: dict):
     """The program's configuration for a configuration file, checked
-    against the file's keys: a mismatch is an error, not a warning."""
+    against the file's keys by its block family (``PROGRAM_FIELDS`` and
+    ``program_rules``): a mismatch is an error, not a warning."""
     _, _, get_config, _, _ = import_program()
+    fam = cell.family(conf)
     p = conf["program"]
     cfg = get_config(p["arch"], p["variant"]).replace(**p["replace"])
     c = conf["config"]
-    bad = [(k, c[k], getattr(cfg, f)) for k, f in CONFIG_FIELDS.items()
+    bad = [(k, c[k], getattr(cfg, f)) for k, f in fam.PROGRAM_FIELDS.items()
            if c[k] != getattr(cfg, f)]
-    eps = c.get("layer_norm_eps", c.get("rms_norm_eps"))
-    if eps != cfg.norm_eps or c["norm"] != cfg.norm_type:
-        bad.append(("norm", (c["norm"], eps), (cfg.norm_type, cfg.norm_eps)))
-    if (c["hidden_act"] == "silu") != cfg.gated_mlp:
-        bad.append(("hidden_act", c["hidden_act"], cfg.act))
+    bad += fam.program_rules(c, cfg)
     if bad:
         raise SystemExit(f"chipbench: the program's configuration departs "
                          f"from {conf['name']}'s file: {bad}")
@@ -91,18 +80,26 @@ _norm = jax.jit(jnp.linalg.norm)
 
 def _readings(tree, per_leaf, slot=None) -> dict:
     """name -> norm of every leaf of a params-shaped tree; ``per_leaf``
-    maps (path, device array) to the norm (or per-row norms)."""
-    out = {}
+    maps (path, device array) to the norm (or per-row norms).  Row ``r``
+    of layer group ``g`` is layer ``r`` plus the rows of groups before
+    ``g``."""
+    leaves = []
     for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
         parts = _name(path)
         if slot is not None:
             if parts[-1] != slot:
                 continue
             parts = parts[:-1]
+        leaves.append((parts, leaf))
+    rows = {int(parts[1]): leaf.shape[0] for parts, leaf in leaves
+            if parts[0] == "groups"}
+    first = {g: sum(rows[h] for h in rows if h < g) for g in rows}
+    out = {}
+    for parts, leaf in leaves:
         value = per_leaf(".".join(parts), _on_device(leaf))
         if parts[0] == "groups":
             rest = ".".join(parts[2:])
-            for i, x in enumerate(np.asarray(value)):
+            for i, x in enumerate(np.asarray(value), first[int(parts[1])]):
                 out[f"layers.{i}.{rest}"] = float(x)
         else:
             out[".".join(parts)] = float(value)
@@ -110,10 +107,11 @@ def _readings(tree, per_leaf, slot=None) -> dict:
 
 
 def load_weights(state, conf: dict, key):
-    """The state with the run's weights (``reference.model.init_params``,
-    made on the device in one jitted call) in place of the ones
-    ``Engine.init`` drew, each array where the engine keeps it."""
-    fresh = jax.jit(functools.partial(M.init_params, conf["config"]))(key)
+    """The state with the run's weights (``reference.model.init_params``
+    of the family's ``param_tree``, made on the device in one jitted call)
+    in place of the ones ``Engine.init`` drew, each array where the engine
+    keeps it."""
+    fresh = M.make_params(cell.family(conf), conf["config"], key)
     if jax.tree.structure(fresh) != jax.tree.structure(state.params):
         raise SystemExit("chipbench: the program's parameters are not laid "
                          "out as the reference's")
@@ -133,7 +131,7 @@ def grad_readings(state, b1: float) -> dict:
 
 def change_readings(state, conf: dict, key) -> dict:
     """Norms of each leaf's change from the run's weights (made again)."""
-    init = jax.jit(functools.partial(M.init_params, conf["config"]))(key)
+    init = M.make_params(cell.family(conf), conf["config"], key)
     flat = {".".join(_name(p)): a for p, a in
             jax.tree_util.tree_flatten_with_path(init)[0]}
     diff = jax.jit(lambda a, b: a - b)

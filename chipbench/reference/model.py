@@ -1,16 +1,26 @@
-"""Plain float32 reference of the decoder blocks the benchmark trains.
+"""What every block family of the plain float32 reference shares.
 
 Written from the published equations and from the configuration file's
 keys (Hugging Face names), in straightforward ``jax.numpy``; it imports
-nothing of the program under test.  One function per block family:
+nothing of the program under test.  A block family is a module of its
+own (``reference/decoder.py``, ...), named by the configuration file's
+``"reference"`` key; it gives:
 
-* ``layernorm`` blocks (BERT-Large as run): pre-LN, biased q/k/v/o
-  projections, RoPE, causal softmax attention, the tanh GELU MLP with
-  biases (Hendrycks & Gimpel 2016; the original BERT code's form);
-* ``rmsnorm`` blocks (Granite-3.0 as run): pre-RMSNorm, grouped-query
-  attention with RoPE (theta from the file), the SwiGLU MLP (Shazeer 2020).
+* ``param_tree(c)``: every stored array as ``(shape, init)``, nested as
+  the program stores them: ``embed``, ``head`` and ``groups``, a tuple of
+  layer groups in the program's order, each stacked over a leading axis
+  of its rows;
+* ``layer(c, ar, w, x, q_block, group)``: one layer of group ``group``,
+  ``x`` (b, s, d) -> ``(x, aux)``, aux a float32 scalar: the layer's
+  auxiliary loss over the block's sequences (0.0 where there is none);
+* ``flops_per_token(c, seq)``: the model FLOPs one trained token costs;
+* ``PROGRAM_FIELDS`` (file key -> the program's field) and
+  ``program_rules(c, cfg)``: what ``program.program_config`` checks;
+* optionally ``head_loss_sum``, in place of the one here.
 
-RoPE rotates each pair of dims (2i, 2i+1) by position * theta^(-2i/d_head)
+Here: ``Arith``, the fp8 rounding, ``init_params`` over any such tree,
+RMS and layer norm, RoPE, causal attention and the head's loss.  RoPE
+rotates each pair of dims (2i, 2i+1) by position * theta^(-2i/d_head)
 (Su et al., RoFormer).  Every contraction goes through one ``Arith``, so
 the same code computes in float32 (``highest`` matmul precision, the
 reference) or in scaled fp8 (the control).  The arrays are named and
@@ -19,6 +29,7 @@ weights once, here, for both.
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -74,55 +85,16 @@ class Arith:
 # ---------------------------------------------------------------------------
 # parameters
 # ---------------------------------------------------------------------------
-def layer_shapes(c: dict) -> dict:
-    """One layer's parameters: name -> (shape, init)."""
-    d, h = c["hidden_size"], c["num_attention_heads"]
-    kv, dh, ff = c["num_key_value_heads"], c["head_dim"], c["intermediate_size"]
-    ln = ({"scale": ((d,), "ones"), "bias": ((d,), "zeros")}
-          if c["norm"] == "layernorm" else {"scale": ((d,), "ones")})
-    attn = {"wq": ((d, h, dh), "normal"), "wk": ((d, kv, dh), "normal"),
-            "wv": ((d, kv, dh), "normal"), "wo": ((h, dh, d), "normal")}
-    if c["attention_bias"]:
-        attn.update(bq=((h, dh), "zeros"), bk=((kv, dh), "zeros"),
-                    bv=((kv, dh), "zeros"), bo=((d,), "zeros"))
-    if c["hidden_act"] == "silu":
-        mlp = {"w_gate": ((d, ff), "normal"), "w_in": ((d, ff), "normal"),
-               "w_out": ((ff, d), "normal")}
-    else:
-        mlp = {"w_in": ((d, ff), "normal"), "b_in": ((ff,), "zeros"),
-               "w_out": ((ff, d), "normal"), "b_out": ((d,), "zeros")}
-    return {"attn": attn, "ln1": dict(ln), "ln2": dict(ln), "mlp": mlp}
-
-
-def param_tree(c: dict) -> dict:
-    """Every stored array as (shape, init), nested as the program stores
-    them: one layer group whose arrays are stacked over a leading layer
-    axis, the embedding, and the head."""
-    n, d = c["num_hidden_layers"], c["hidden_size"]
-    ln_f = {"scale": ((d,), "ones")}
-    if c["norm"] == "layernorm":
-        ln_f["bias"] = ((d,), "zeros")
-    head = {"ln_f": ln_f}
-    if not c["tie_word_embeddings"]:
-        head["out"] = ((d, c["vocab_size"]), "normal")
-    layers = {k: {n_: ((n,) + s, i) for n_, (s, i) in v.items()}
-              for k, v in layer_shapes(c).items()}
-    return {"embed": {"tok": ((c["vocab_size"], d), "normal")},
-            "groups": (layers,), "head": head}
-
-
 def _is_spec(x) -> bool:
     return isinstance(x, tuple) and len(x) == 2 and isinstance(x[1], str)
 
 
-def init_params(c: dict, key) -> dict:
-    """The run's weights, from its key: N(0, initializer_range) for every
-    weight array and the embedding, zeros for biases, ones for norm
-    scales, one key per array (split in the tree's sorted order).  Jit
-    it to make them on the device in one call."""
-    leaves, treedef = jax.tree.flatten(param_tree(c), is_leaf=_is_spec)
+def init_params(tree: dict, std: float, key) -> dict:
+    """The run's weights for a ``param_tree``, from its key: N(0, std) for
+    every weight array and the embedding, zeros for biases, ones for norm
+    scales, one key per array (split in the tree's sorted order)."""
+    leaves, treedef = jax.tree.flatten(tree, is_leaf=_is_spec)
     keys = jax.random.split(key, len(leaves))
-    std = c["initializer_range"]
 
     def leaf(k, spec):
         shape, init = spec
@@ -134,6 +106,13 @@ def init_params(c: dict, key) -> dict:
 
     return jax.tree.unflatten(treedef, [leaf(k, s) for k, s in
                                         zip(keys, leaves)])
+
+
+def make_params(fam, c: dict, key) -> dict:
+    """``init_params`` of family ``fam``'s tree at the file's
+    ``initializer_range``, made on the device in one jitted call."""
+    return jax.jit(functools.partial(init_params, fam.param_tree(c),
+                                     c["initializer_range"]))(key)
 
 
 # ---------------------------------------------------------------------------
@@ -160,14 +139,10 @@ def rope(x, theta):
                      axis=-1).reshape(x.shape)
 
 
-def gelu_tanh(x):
-    return 0.5 * x * (1.0 + jnp.tanh(math.sqrt(2.0 / math.pi)
-                                     * (x + 0.044715 * x ** 3)))
-
-
 def attention(c, ar: Arith, q, k, v, q_block: int):
     """Causal softmax attention, exact, one block of queries at a time so
-    that the (queries x keys) scores of a long sequence fit."""
+    that the (queries x keys) scores of a long sequence fit.  The values'
+    head size may differ from the queries' (as in latent attention)."""
     b, s, h, dh = q.shape
     rep = h // k.shape[2]
     k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
@@ -185,29 +160,7 @@ def attention(c, ar: Arith, q, k, v, q_block: int):
 
     qs = q.reshape(b, nb, q_block, h, dh).swapaxes(0, 1)
     o = jax.lax.map(block, (qs, jnp.arange(nb)))
-    return o.swapaxes(0, 1).reshape(b, s, h, dh)
-
-
-def layer(c, ar: Arith, w, x, q_block: int):
-    """One pre-norm decoder block: x (b, s, d) -> (b, s, d)."""
-    a_w, m_w = w["attn"], w["mlp"]
-    h = norm(c, w["ln1"], x)
-    q = ar.einsum("bsd,dhe->bshe", h, a_w["wq"])
-    k = ar.einsum("bsd,dhe->bshe", h, a_w["wk"])
-    v = ar.einsum("bsd,dhe->bshe", h, a_w["wv"])
-    if c["attention_bias"]:
-        q, k, v = q + a_w["bq"], k + a_w["bk"], v + a_w["bv"]
-    q, k = rope(q, c["rope_theta"]), rope(k, c["rope_theta"])
-    o = ar.einsum("bshe,hed->bsd", attention(c, ar, q, k, v, q_block),
-                  a_w["wo"])
-    x = x + (o + a_w["bo"] if c["attention_bias"] else o)
-    h = norm(c, w["ln2"], x)
-    if c["hidden_act"] == "silu":
-        u = jax.nn.silu(ar.einsum("bsd,df->bsf", h, m_w["w_gate"])) \
-            * ar.einsum("bsd,df->bsf", h, m_w["w_in"])
-        return x + ar.einsum("bsf,fd->bsd", u, m_w["w_out"])
-    u = gelu_tanh(ar.einsum("bsd,df->bsf", h, m_w["w_in"]) + m_w["b_in"])
-    return x + ar.einsum("bsf,fd->bsd", u, m_w["w_out"]) + m_w["b_out"]
+    return o.swapaxes(0, 1).reshape(b, s, h, v.shape[-1])
 
 
 def head_loss_sum(c, ar: Arith, static, x, targets, mask):

@@ -1,10 +1,16 @@
 """A few Adam steps of the plain reference, layer by layer, in float32.
 
 ``train`` starts from the weights that ``model.init_params`` makes from
-the run's key (the program is given the same), takes ``len(batches)`` steps of Adam (Kingma &
-Ba, algorithm 1, with the step size folded into alpha_t) and returns what
-the check compares: each step's loss, every layer leaf's gradient norm at
-the first step, and every leaf's change after the last.
+the run's key for the configuration's block family (the program is given
+the same), takes ``len(batches)`` steps of Adam (Kingma & Ba, algorithm
+1, with the step size folded into alpha_t) and returns what the check
+compares: each step's loss, every layer leaf's gradient norm at the
+first step, and every leaf's change after the last.  Layers are numbered
+through the family's layer groups in order.
+
+The loss is the masked cross-entropy's sum over its weight W plus, for
+each layer, the mean over its blocks of rows of the layer's auxiliary
+loss, as the program adds the mean over its microbatches.
 
 It is plain, not small: it works layer by layer, each layer's forward
 and backward pass over blocks of rows, and where the float32 weights and
@@ -15,13 +21,12 @@ layer from its stored input.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import SingleDeviceSharding
 
+from chipbench import cell
 from chipbench.reference import model as M
 
 TOKENS_PER_ROW_BLOCK = 8192
@@ -61,10 +66,14 @@ class Store:
 
 
 class Reference:
-    """The reference's compiled pieces for one configuration and shape."""
+    """The reference's compiled pieces for one configuration file and
+    shape."""
 
-    def __init__(self, c: dict, opt: dict, batch: int, seq: int,
+    def __init__(self, conf: dict, batch: int, seq: int,
                  arith: str = "float32"):
+        fam = cell.family(conf)
+        c, opt = conf["config"], conf["optimizer"]
+        head_loss_sum = getattr(fam, "head_loss_sum", M.head_loss_sum)
         ar = M.Arith(arith)
         rb = max(1, min(batch, TOKENS_PER_ROW_BLOCK // seq))
         while batch % rb:
@@ -75,16 +84,22 @@ class Reference:
         def blocks(x, n):
             return x.reshape((x.shape[0] // n, n) + x.shape[1:])
 
-        def fwd(w, x):
-            ys = jax.lax.map(lambda xb: M.layer(c, ar, w, xb, qb),
-                             blocks(x, rb))
-            return ys.reshape(x.shape)
+        def fwd(w, x, group):
+            ys, aux = jax.lax.map(
+                lambda xb: fam.layer(c, ar, w, xb, qb, group), blocks(x, rb))
+            return ys.reshape(x.shape), aux.mean()
 
-        def bwd(w, x, dy, scale):
+        def bwd(w, x, dy, scale, group):
+            # dy is the gradient of the cross-entropy's sum, which ``scale``
+            # (1/W) turns into the loss's; a block's aux enters the loss
+            # with weight 1/blocks
+            aux_ct = 1.0 / (scale * (x.shape[0] // rb))
+
             def body(dw, xy):
-                _, vjp = jax.vjp(lambda w_, x_: M.layer(c, ar, w_, x_, qb),
-                                 w, xy[0])
-                dw_b, dx_b = vjp(xy[1])
+                _, vjp = jax.vjp(
+                    lambda w_, x_: fam.layer(c, ar, w_, x_, qb, group),
+                    w, xy[0])
+                dw_b, dx_b = vjp((xy[1], aux_ct))
                 return jax.tree.map(jnp.add, dw, dw_b), dx_b
             zero = jax.tree.map(jnp.zeros_like, w)
             dw, dx = jax.lax.scan(body, zero, (blocks(x, rb), blocks(dy, rb)))
@@ -96,7 +111,7 @@ class Reference:
             def body(carry, blk):
                 xb, tb_, mb = blk
                 loss, (gs, gx) = jax.value_and_grad(
-                    lambda s, xx: M.head_loss_sum(c, ar, s, xx, tb_, mb),
+                    lambda s, xx: head_loss_sum(c, ar, s, xx, tb_, mb),
                     argnums=(0, 1))(static, xb)
                 return (carry[0] + loss,
                         jax.tree.map(jnp.add, carry[1], gs)), gx
@@ -127,8 +142,8 @@ class Reference:
             return p, m, v
 
         self.embed = jax.jit(lambda tok, ids: tok[ids])
-        self.fwd = jax.jit(fwd)
-        self.bwd = jax.jit(bwd)
+        self.fwd = jax.jit(fwd, static_argnames="group")
+        self.bwd = jax.jit(bwd, static_argnames="group")
         self.head = jax.jit(head)
         self.static_grad = jax.jit(static_grad)
         self.adam = jax.jit(adam, donate_argnums=(0, 2, 3))
@@ -137,14 +152,18 @@ class Reference:
             lambda x, y: jnp.linalg.norm(x - y), a, b))
 
 
-def initial_params(c: dict, key) -> tuple:
-    """(static, layers): the embedding and head as one tree, and one tree
-    per layer, all on the device, from ``model.init_params``."""
-    p = jax.jit(functools.partial(M.init_params, c))(key)
-    stacked = p["groups"][0]
-    layers = [jax.tree.map(lambda a, i=i: a[i], stacked)
-              for i in range(c["num_hidden_layers"])]
-    return {"embed": p["embed"], "head": p["head"]}, layers
+def initial_params(fam, c: dict, key) -> tuple:
+    """(static, layers, groups): the embedding and head as one tree, one
+    tree per layer through the layer groups in order, all on the device,
+    from ``model.init_params``, and each layer's group."""
+    p = M.make_params(fam, c, key)
+    layers, groups = [], []
+    for g, stacked in enumerate(p.pop("groups")):
+        rows = jax.tree.leaves(stacked)[0].shape[0]
+        layers += [jax.tree.map(lambda a, i=i: a[i], stacked)
+                   for i in range(rows)]
+        groups += [g] * rows
+    return p, layers, groups
 
 
 def _named(tree, prefix) -> dict:
@@ -158,12 +177,14 @@ def _tree_bytes(tree) -> int:
     return sum(a.nbytes for a in jax.tree.leaves(tree))
 
 
-def train(c: dict, opt: dict, key, batches: list, arith: str = "float32",
+def train(conf: dict, key, batches: list, arith: str = "float32",
           ref: Reference | None = None) -> dict:
-    """Readings of ``len(batches)`` reference steps from the init."""
+    """Readings of ``len(batches)`` reference steps from the init, for
+    configuration file ``conf``."""
     B, S = batches[0]["tokens"].shape
-    ref = ref or Reference(c, opt, B, S, arith)
-    static, params = initial_params(c, key)
+    ref = ref or Reference(conf, B, S, arith)
+    fam, c = cell.family(conf), conf["config"]
+    static, params, groups = initial_params(fam, c, key)
     n = len(params)
     # Adam's slots of the layers, and the layers' inputs, wait in host
     # memory where they would not leave the device room to work in
@@ -180,17 +201,18 @@ def train(c: dict, opt: dict, key, batches: list, arith: str = "float32",
         ids = jnp.asarray(b["tokens"])
         W = float(np.sum(b["mask"]))
         x = ref.embed(static["embed"]["tok"], ids)
-        xs = []
+        xs, aux = [], []
         for i in range(n):
             xs.append(park_x.put(x))
-            x = ref.fwd(params[i], x)
+            x, aux_i = ref.fwd(params[i], x, group=groups[i])
+            aux.append(aux_i)
         loss, g_static, dx = ref.head(static, x, jnp.asarray(b["targets"]),
                                       jnp.asarray(b["mask"]))
-        losses.append(float(loss) / W)
+        losses.append(float(loss) / W + sum(float(a) for a in aux))
         del x
         for i in reversed(range(n)):
             dw, dx = ref.bwd(params[i], park_x.get(xs.pop()), dx,
-                             jnp.float32(1.0 / W))
+                             jnp.float32(1.0 / W), group=groups[i])
             if t == 1:
                 grads.update(_named(ref.tree_norms(dw), f"layers.{i}."))
             params[i], mi, vi = ref.adam(params[i], dw, park.get(m[i]),
@@ -203,7 +225,7 @@ def train(c: dict, opt: dict, key, batches: list, arith: str = "float32",
         static, sm, sv = ref.adam(static, g_static, sm, sv, jnp.float32(t))
         del dx, g_static
     del m, v, sm, sv
-    static0, params0 = initial_params(c, key)
+    static0, params0, _ = initial_params(fam, c, key)
     change = _named(ref.diff_norms(static, static0), "")
     for i in range(n):
         change.update(_named(ref.diff_norms(params[i], params0[i]),

@@ -201,13 +201,15 @@ def run(c: dict, seed: int, seconds: float, traced: bool) -> dict:
         ctx = {
             "window_compiles": window_compiles,
             "tokens_per_s": tokens_per_s,
-            "flops_per_token": flops.flops_per_token(conf["config"],
-                                                     traffic["seq"]),
+            "flops_per_token": flops.flops_per_token(conf, traffic["seq"]),
             "peak": peaks.peak(dev.device_kind),
             "trace": reduced,
             "state": state,
             "step_memory": jax.jit(eng.step_fn, donate_argnums=(0,)).lower(
                 state, batches[0]).compile().memory_analysis(),
+            # the cell's files as ``cell.load`` gives them, for a reader
+            # that reckons from the shapes the cell ran
+            "config": conf, "traffic": traffic, "workload": wl,
         }
         metrics = {}
         for m_ in c["per_layer"]:
@@ -235,8 +237,7 @@ def run(c: dict, seed: int, seconds: float, traced: bool) -> dict:
     del state, m, batch, batches, eng
     gc.collect()
     t = time.perf_counter()
-    want = reference.train(conf["config"], conf["optimizer"], s["key"],
-                           s["checked"])
+    want = reference.train(conf, s["key"], s["checked"])
     print(f"reference: {n_check} steps in {time.perf_counter() - t:.3f} s; "
           f"losses {want['losses']}, program {s['got']['losses']}",
           flush=True)

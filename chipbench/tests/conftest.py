@@ -3,6 +3,7 @@
     PYTHONPATH=src JAX_PLATFORMS=cpu python -m pytest -q chipbench/tests
 """
 import copy
+import json
 import sys
 from pathlib import Path
 
@@ -13,31 +14,41 @@ for p in (ROOT, ROOT / "src"):
     if str(p) not in sys.path:
         sys.path.insert(0, str(p))
 
-# the program's smoke presets (configs/*.py ``smoke()``), in the file's keys
-SMOKE = {
-    "bert-large.b32-s512": dict(
-        hidden_size=128, num_hidden_layers=2, num_attention_heads=4,
-        num_key_value_heads=4, head_dim=32, intermediate_size=256,
-        vocab_size=512),
-    "granite-3-8b-l4.b4-s4096": dict(
-        hidden_size=256, num_hidden_layers=2, num_attention_heads=8,
-        num_key_value_heads=2, head_dim=32, intermediate_size=512,
-        vocab_size=512, attention_multiplier=32 ** -0.5),
-}
+BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
+# each configuration's first cell: the smoke tests run once a configuration
+FIRST_CELLS = sorted(
+    next(w["name"] for w in BENCH["workloads"] if w["config"] == cf["name"])
+    for cf in BENCH["configs"])
 
 
 def smoke_cell(name: str) -> dict:
     """The cell ``name`` as ``cell.load`` gives it, cut to the program's
-    smoke preset and a batch of 4 x 64 tokens."""
+    smoke preset (its configuration file's ``"smoke"`` sizes) and a batch
+    of 4 x 64 tokens."""
     from chipbench import cell
     c = copy.deepcopy(cell.load(name))
     conf = c["config"]
     conf["program"] = dict(conf["program"], variant="smoke", replace={})
-    conf["config"].update(SMOKE[name])
+    conf["config"].update(conf["smoke"])
     c["traffic"] = dict(c["traffic"], batch=4, seq=64, doc_len=[16, 64])
     return c
 
 
-@pytest.fixture(params=sorted(SMOKE))
+def grads_by_name(tree) -> dict:
+    """Norms of a params-shaped tree, named as the reference names its
+    readings (``layers.<i>`` through every layer group)."""
+    import jax
+    import jax.numpy as jnp
+
+    from chipbench import program
+
+    def per_leaf(path, a):
+        if path.startswith("groups"):
+            return jax.vmap(jnp.linalg.norm)(a)
+        return jnp.linalg.norm(a)
+    return program._readings(tree, per_leaf)
+
+
+@pytest.fixture(params=FIRST_CELLS)
 def cell_name(request):
     return request.param

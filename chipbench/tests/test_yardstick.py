@@ -9,11 +9,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from conftest import ROOT, smoke_cell
+from conftest import ROOT, grads_by_name, smoke_cell
 
 from chipbench import cell, check, data, flops, program
 from chipbench import run as runner
 from chipbench.calibrate import half
+from chipbench.reference import decoder
+from chipbench.reference import model as M
 from chipbench.reference import train as reference
 
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -41,8 +43,9 @@ def test_program_config_matches_each_file():
 
 
 def test_bert_large_flops_per_token():
-    conf = cell.load("bert-large.b32-s512")["config"]["config"]
-    assert flops.matmul_params(conf) == 24 * 12_582_912 + 1024 * 30522
+    conf = cell.load("bert-large.b32-s512")["config"]
+    assert decoder.matmul_params(conf["config"]) == (24 * 12_582_912
+                                                     + 1024 * 30522)
     want = 6 * 333_244_416 + 12 * 24 * 512 * 1024
     assert flops.flops_per_token(conf, 512) == want
     assert abs(want - 2.15e9) / 2.15e9 < 0.01
@@ -72,24 +75,6 @@ def test_run_without_a_tpu_exits_nonzero_and_prints_no_result():
     assert '"correct"' not in p.stdout
 
 
-def model_init(c, key):
-    from chipbench.reference import model as M
-    return M.init_params(c, key)
-
-
-def _grads_by_name(g) -> dict:
-    out = {}
-    for path, leaf in jax.tree_util.tree_flatten_with_path(g)[0]:
-        parts = [str(getattr(p, "key", getattr(p, "idx", p))) for p in path]
-        if parts[0] == "groups":
-            for i in range(leaf.shape[0]):
-                out[f"layers.{i}." + ".".join(parts[2:])] = float(
-                    jnp.linalg.norm(leaf[i]))
-        else:
-            out[".".join(parts)] = float(jnp.linalg.norm(leaf))
-    return out
-
-
 def test_reference_agrees_with_the_programs_model(cell_name):
     """Same loss and gradients as ``LayeredModel`` in float32, from the
     same weights, laid out as the program lays out its own."""
@@ -99,7 +84,7 @@ def test_reference_agrees_with_the_programs_model(cell_name):
     cfg = program.program_config(conf).replace(dtype="float32")
     model = LayeredModel(cfg)
     key = runner.run_key(2**31 + 3)
-    params = model_init(conf["config"], key)
+    params = M.make_params(cell.family(conf), conf["config"], key)
     assert jax.tree.structure(params) == jax.tree.structure(
         model.abstract_params())
     b = data.make_batch(c["traffic"], conf["config"]["vocab_size"], 7, 0)
@@ -108,9 +93,9 @@ def test_reference_agrees_with_the_programs_model(cell_name):
             lambda p: model.full_loss(p, {k: jnp.asarray(v)
                                           for k, v in b.items()}),
             has_aux=True)(params)
-    want = reference.train(conf["config"], conf["optimizer"], key, [b])
+    want = reference.train(conf, key, [b])
     assert abs(float(loss) - want["losses"][0]) < 1e-5 * float(loss)
-    got = _grads_by_name(g)
+    got = grads_by_name(g)
     assert set(got) == set(want["grad_norms"])
     med = np.median(list(want["grad_norms"].values()))
     for k, v in want["grad_norms"].items():
@@ -169,12 +154,12 @@ def test_the_control_is_not_correct(cell_name):
     """The reference in fp8, in the program's place, fails the cell's own
     limits against the float32 reference."""
     c = smoke_cell(cell_name)
-    cc, opt = c["config"]["config"], c["config"]["optimizer"]
+    conf = c["config"]
     key = runner.run_key(2**31 + 17)
-    batches = data.make_batches(c["traffic"], cc["vocab_size"],
+    batches = data.make_batches(c["traffic"], conf["config"]["vocab_size"],
                                 2**31 + 17)[:3]
-    want = reference.train(cc, opt, key, batches)
-    ctl = reference.train(cc, opt, key, batches, arith="fp8")
+    want = reference.train(conf, key, batches)
+    ctl = reference.train(conf, key, batches, arith="fp8")
     ok, rows = check.verdict(check.gaps(ctl, want),
                              c["workload"]["check"]["limits"])
     assert not ok, rows
@@ -182,12 +167,13 @@ def test_the_control_is_not_correct(cell_name):
 
 def test_half_batch_reference_reads_as_a_fault(cell_name):
     c = smoke_cell(cell_name)
-    cc, opt = c["config"]["config"], c["config"]["optimizer"]
+    conf = c["config"]
     key = runner.run_key(5)
-    batches = data.make_batches(c["traffic"], cc["vocab_size"], 5)[:3]
-    want = reference.train(cc, opt, key, batches)
-    same = reference.train(cc, opt, key, batches)
-    hb = reference.train(cc, opt, key, [half(b) for b in batches])
+    batches = data.make_batches(c["traffic"], conf["config"]["vocab_size"],
+                                5)[:3]
+    want = reference.train(conf, key, batches)
+    same = reference.train(conf, key, batches)
+    hb = reference.train(conf, key, [half(b) for b in batches])
     assert check.verdict(check.gaps(same, want), FLOAT32_LIMITS)[0]
     assert not check.verdict(check.gaps(hb, want), FLOAT32_LIMITS)[0]
 
